@@ -23,9 +23,8 @@ from __future__ import annotations
 
 import json
 import os
-from argparse import Namespace
 
-from repro.cli import _numeric_plan
+from repro.api import numeric_plan
 from repro.obs.calibrate import calibrate_profile, replan
 from repro.serverless.execution import ExecutionConfig
 from repro.serverless.runtime import run_plan
@@ -40,9 +39,8 @@ ABS_SLACK = 0.02
 
 def rows(fast: bool = False):
     steps = 2 if fast else 3     # >= 2 so the JIT-compile step-0 warmup drops
-    plan, prof, ex = _numeric_plan(Namespace(
-        model="phi3-mini-3.8b", platform="aws", n_layers=4, seq=16,
-        batch=8, dp=2, stages=2, lambda_ml_sync=False))
+    plan, prof, ex = numeric_plan("phi3-mini-3.8b@reduced4", stages=2, dp=2,
+                                  batch=8, seq=16)
     rp = plan.resolve(profile=prof)
     res = run_plan(rp.profile, rp.platform, rp.config,
                    rp.total_micro_batches,

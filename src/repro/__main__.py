@@ -4,4 +4,7 @@ import sys
 from repro.cli import main
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -17,6 +17,7 @@ from repro.api.plan import (
     ResolvedPlan,
     profile_fingerprint,
 )
+from repro.api.numeric import NumericDeployment, numeric_plan
 from repro.api.plan_cache import PlanCache, resolve_plan_cache
 from repro.api.session import (
     DEFAULT_ALPHA,
@@ -30,6 +31,8 @@ __all__ = [
     "DeploymentPlan",
     "ExecutionConfig",
     "InfeasiblePlanError",
+    "NumericDeployment",
+    "numeric_plan",
     "PlanCache",
     "PlanCompatibilityError",
     "ResolvedPlan",

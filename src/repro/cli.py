@@ -242,78 +242,21 @@ def _cmd_simulate(args) -> int:
 
 
 # ---------------------------------------------------------------- emulate
-def _numeric_partition(cfg, n_stages: int) -> tuple:
-    """Boundary vector over the arch profile ([embed]+layers+[head]) cutting
-    at period boundaries so every stage owns whole instances."""
-    L = cfg.n_layers + 2
-    plen = cfg.period_len
-    n_inst = cfg.n_periods
-    assert n_stages <= n_inst, (n_stages, n_inst)
-    x = [0] * (L - 1)
-    for s in range(1, n_stages):
-        inst = round(s * n_inst / n_stages)
-        layer = inst * plen               # first layer of stage s
-        x[layer] = 1                      # cut after profile layer `layer`
-    return tuple(x)
-
-
-def _min_feasible_z(profile, platform, x, d, mu):
-    from repro.core import planner
-
-    stage_mem = planner._min_feasible_stage_mem(profile, platform, x, d, mu)
-    if stage_mem is None:
-        raise SystemExit("no memory option fits the per-stage working set")
-    return planner._expand_z(stage_mem, x, profile.L)
-
-
 def _numeric_plan(args):
     """Numeric-mode setup: period-aligned manual partition + Execution."""
-    import dataclasses
+    from repro.api import numeric_plan
 
-    import jax
-
-    from repro.api import DeploymentPlan
-    from repro.configs import ARCH_IDS, get_config
-    from repro.configs.base import InputShape
-    from repro.core.perfmodel import Config
-    from repro.core.profiler import arch_model_profile
-    from repro.data.synthetic import make_batch
-    from repro.models import registry
-    from repro.optim import AdamW
-    from repro.serverless.runtime import Execution
-
-    platform = get_platform(args.platform)
-    arch = args.model or "phi3-mini-3.8b"
-    if arch not in ARCH_IDS:
-        raise SystemExit(
-            f"--numerics runs real JAX and needs an assigned arch id, got "
-            f"{arch!r}; archs: {sorted(ARCH_IDS)}")
-    cfg = dataclasses.replace(get_config(arch).reduced(),
-                              n_layers=args.n_layers)
-    seq = args.seq if args.seq is not None else 16
-    batch = 64 if args.batch is None else args.batch
-    shape = InputShape("emulate", seq, batch, "train")
-    mu = max(1, batch // (args.dp * 2))
-    if batch % (args.dp * mu):
-        raise SystemExit(f"--batch {batch} must be divisible by dp*mu "
-                         f"= {args.dp}*{mu}")
-    if args.stages > cfg.n_periods:
-        raise SystemExit(
-            f"--stages {args.stages} exceeds the {cfg.n_periods} period "
-            f"instances of {arch} at --n-layers {args.n_layers}")
-    mb = batch // (args.dp * mu)
-    prof = arch_model_profile(cfg, platform, seq=seq, micro_batch=mb)
-    x = _numeric_partition(cfg, args.stages)
-    z = _min_feasible_z(prof, platform, x, args.dp, mu)
-    plan = DeploymentPlan.from_config(
-        prof, platform, Config(x=x, d=args.dp, z=z), args.dp * mu,
-        model=f"{arch}@reduced{args.n_layers}",   # replayable spelling
-        pipelined_sync=not args.lambda_ml_sync, seq=seq,
-        micro_batch=mb, solver="manual")
-    params0 = registry.init_params(cfg, jax.random.PRNGKey(0))
-    ex = Execution(cfg=cfg, optimizer=AdamW(lr=1e-2), init_params=params0,
-                   batch_fn=lambda k: make_batch(cfg, shape, step=k))
-    return plan, prof, ex
+    model = args.model or "phi3-mini-3.8b@reduced4"
+    try:
+        with _operator_errors():    # unknown model spellings
+            return numeric_plan(
+                model, stages=args.stages, dp=args.dp,
+                batch=64 if args.batch is None else args.batch,
+                seq=16 if args.seq is None else args.seq,
+                platform=args.platform,
+                pipelined_sync=not args.lambda_ml_sync)
+    except ValueError as e:
+        raise SystemExit(f"error: {e}") from None
 
 
 def _cmd_emulate(args) -> int:
@@ -816,11 +759,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("-o", "--out", default=None,
                    help="also save the executed plan JSON here")
     p.add_argument("--numerics", action="store_true",
-                   help="run real JAX through the store (reduced arch)")
+                   help="run real JAX through the store; --model is an arch "
+                        "spelling: <arch> (published), <arch>@reduced[<L>] "
+                        "(CPU-sized) or <arch>@depth<L> (published widths, "
+                        "L layers); default phi3-mini-3.8b@reduced4")
     p.add_argument("--stages", type=int, default=2, help="numeric mode stages")
     p.add_argument("--dp", type=int, default=2, help="numeric mode DP degree")
-    p.add_argument("--n-layers", type=int, default=4,
-                   help="numeric mode depth")
     p.add_argument("--trace", default=None, metavar="OUT.json",
                    help="record per-worker spans and write a Chrome/Perfetto "
                         "trace with the simulator's predicted timeline "
@@ -917,8 +861,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="saved workload='serve' DeploymentPlan JSON "
                         "(or pass --model + --slo to plan fresh)")
     p.add_argument("--model", default=None,
-                   help="assigned arch id at reduced depth "
-                        "(e.g. phi3-mini-3.8b@reduced)")
+                   help="arch spelling, e.g. phi3-mini-3.8b@reduced "
+                        "(CPU-sized) or phi3-mini-3.8b@depth2 (published "
+                        "widths, 2 layers)")
     p.add_argument("--platform", default="aws", choices=_PLATFORM_CHOICES)
     p.add_argument("--slo", type=float, default=None, metavar="SECONDS",
                    help="per-request latency SLO the plan must meet "

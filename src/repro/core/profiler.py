@@ -110,47 +110,28 @@ def arch_model_profile(cfg: ArchConfig, platform: Platform, *, seq: int = 512,
 
 
 # ------------------------------------------------------- unified resolution
-def known_models():
-    """All model ids the profiler can resolve (paper models + arch ids)."""
-    from repro.configs import ARCH_IDS
-
-    return sorted(_PAPER_MODELS) + sorted(ARCH_IDS)
-
-
 def resolve_profile(model: str, platform: Platform, *, seq=None,
                     micro_batch=None) -> ModelProfile:
     """One front door from a model id to its layer profile.
 
-    Accepts the paper's Table 1 models, any assigned arch id, and the
-    reduced-arch spelling ``<arch>@reduced[<n_layers>]`` that the numeric
-    emulation mode records (so its saved plans replay too); ``None`` keeps
-    each family's own default (paper: micro_batch=4; arch: seq=512,
-    micro_batch=1).  This is the resolution path ``DeploymentPlan.resolve``
-    replays, so the recorded ``profile_args`` must reproduce the profile the
-    plan was solved against."""
-    import dataclasses
-
-    from repro.configs import ARCH_IDS, get_config
+    Accepts the paper's Table 1 models and every arch spelling
+    ``repro.configs.resolve_arch`` reads (``<arch>``, ``<arch>@reduced[<L>]``,
+    ``<arch>@depth<L>``), which is what the numeric emulation mode records
+    (so its saved plans replay too); ``None`` keeps each family's own
+    default (paper: micro_batch=4; arch: seq=512, micro_batch=1).  This is
+    the resolution path ``DeploymentPlan.resolve`` replays, so the recorded
+    ``profile_args`` must reproduce the profile the plan was solved
+    against."""
+    from repro.configs import resolve_arch
 
     if model in _PAPER_MODELS:
         return paper_model_profile(model, platform,
                                    micro_batch=4 if micro_batch is None else micro_batch)
-    base, _, spec = model.partition("@")
-    if base in ARCH_IDS and (not spec or spec.startswith("reduced")):
-        cfg = get_config(base)
-        if spec:
-            cfg = cfg.reduced()
-            depth = spec[len("reduced"):]
-            if depth:
-                try:
-                    cfg = dataclasses.replace(cfg, n_layers=int(depth))
-                except ValueError:
-                    raise KeyError(
-                        f"malformed reduced-arch spec {model!r}: depth "
-                        f"{depth!r} is not an integer") from None
-        return arch_model_profile(cfg, platform,
-                                  seq=512 if seq is None else seq,
-                                  micro_batch=1 if micro_batch is None else micro_batch)
-    raise KeyError(
-        f"unknown model {model!r}; known models: {known_models()} "
-        "(reduced spelling: <arch>@reduced[<L>])")
+    try:
+        cfg = resolve_arch(model)
+    except KeyError as e:
+        raise KeyError(f"{e.args[0]}; paper models: "
+                       f"{sorted(_PAPER_MODELS)}") from None
+    return arch_model_profile(cfg, platform,
+                              seq=512 if seq is None else seq,
+                              micro_batch=1 if micro_batch is None else micro_batch)

@@ -14,10 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -109,7 +105,7 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(length, qr, kr, vr)

@@ -2,39 +2,66 @@
 
 Models call these; ``REPRO_KERNEL_MODE`` picks the backend:
   auto      — Pallas on TPU, reference elsewhere (default)
-  interpret — Pallas in interpret mode (CPU correctness runs)
+  interpret — Pallas in interpret mode (CPU correctness runs; refused on a
+              TPU, where it would quietly run the interpreter)
   ref       — always the jnp oracle
+
+Block sizes are picked to divide the shapes; a shape no legal tiling
+divides runs the oracle.
 """
 from __future__ import annotations
 
 import os
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
+MODES = ("auto", "interpret", "ref")
 
-def _mode() -> str:
+
+def kernel_mode() -> str:
+    """The resolved mode: ``pallas``, ``interpret`` or ``ref``."""
     m = os.environ.get("REPRO_KERNEL_MODE", "auto")
+    if m not in MODES:
+        raise ValueError(f"REPRO_KERNEL_MODE={m!r}; expected one of {MODES}")
+    on_tpu = jax.default_backend() == "tpu"
     if m == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
+        return "pallas" if on_tpu else "ref"
+    if m == "interpret" and on_tpu:
+        raise ValueError(
+            "REPRO_KERNEL_MODE=interpret would run the Pallas interpreter on "
+            "the TPU; unset it to compile the kernels")
     return m
 
 
+def _block(n: int, pref: int, align: int) -> Optional[int]:
+    """Largest tile <= ``pref`` that divides ``n`` and is a multiple of
+    ``align``; the whole dimension when ``n <= pref`` (always a legal
+    block); None when no such tile exists."""
+    if n <= pref:
+        return n
+    for b in range(pref - pref % align, 0, -align):
+        if n % b == 0:
+            return b
+    return None
+
+
 def flash_attention(q, k, v, *, causal=True, window=0, positions=None):
-    mode = _mode()
-    if mode == "ref":
+    mode = kernel_mode()
+    blk = _block(q.shape[1], 128, 8)
+    if mode == "ref" or blk is None:
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                         positions=positions)
     from repro.kernels.flash_attention import flash_attention as fa
 
-    return fa(q, k, v, causal=causal, window=window,
-              interpret=(mode == "interpret"))
+    return fa(q, k, v, causal=causal, window=window, q_block=blk,
+              k_block=blk, interpret=(mode == "interpret"))
 
 
 def decode_attention(q, k_cache, v_cache, length):
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return _ref.decode_attention_ref(q, k_cache, v_cache, length)
     from repro.kernels.decode_attention import decode_attention as da
@@ -59,13 +86,17 @@ def decode_attention_capable(*, n_q_heads: int, n_kv_heads: int,
 
 
 def swiglu(x, w_gate, w_up):
-    mode = _mode()
+    mode = kernel_mode()
     orig = x.shape
     x2 = x.reshape(-1, orig[-1])
-    if mode == "ref" or x2.shape[0] % 8:
+    (T, d), f = x2.shape, w_gate.shape[1]
+    blocks = (_block(T, 256, 8), _block(f, 512, 128), _block(d, 512, 128))
+    if mode == "ref" or None in blocks:
         out = _ref.swiglu_ref(x2, w_gate, w_up)
     else:
         from repro.kernels.swiglu import swiglu as sg
 
-        out = sg(x2, w_gate, w_up, interpret=(mode == "interpret"))
+        tb, fb, db = blocks
+        out = sg(x2, w_gate, w_up, t_block=tb, f_block=fb, d_block=db,
+                 interpret=(mode == "interpret"))
     return out.reshape(*orig[:-1], w_gate.shape[1])

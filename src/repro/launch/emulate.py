@@ -6,7 +6,7 @@ working.  Prefer:
 
     PYTHONPATH=src python -m repro emulate --model bert-large --batch 64
     PYTHONPATH=src python -m repro emulate plan.json --steps 2
-    PYTHONPATH=src python -m repro emulate --numerics --model phi3-mini-3.8b \\
+    PYTHONPATH=src python -m repro emulate --numerics --model phi3-mini-3.8b@reduced4 \\
         --stages 2 --dp 2 --batch 8 --seq 16 --steps 2
 """
 from __future__ import annotations

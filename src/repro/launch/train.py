@@ -5,7 +5,12 @@ runs any mesh of fake host devices for bring-up, e.g.:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
     PYTHONPATH=src python -m repro.launch.train \\
-        --arch phi3-mini-3.8b --reduced --data 2 --model 4 --steps 20
+        --arch phi3-mini-3.8b@reduced --data 2 --model 4 --stages 4 \\
+        --steps 20
+
+``--arch`` takes any spelling of ``repro.configs.resolve_arch``: the
+published config, ``@reduced[<L>]`` (CPU-sized) or ``@depth<L>`` (published
+widths, ``L`` layers).
 
 ``--plan auto`` asks core.tpu_planner for the best (stages x tp x mu x remat)
 factorization instead of the config default.  Checkpoints via the
@@ -18,10 +23,10 @@ import dataclasses
 import time
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.checkpoint import FunctionManager
-from repro.configs import get_config, INPUT_SHAPES
+from repro.configs import INPUT_SHAPES, resolve_arch
 from repro.configs.base import InputShape
 from repro.core import sharding, tpu_planner
 from repro.core.plan import make_plan
@@ -29,13 +34,18 @@ from repro.data.synthetic import make_batch
 from repro.launch.mesh import make_production_mesh, make_test_mesh
 from repro.models import registry
 from repro.optim import AdamW
-from repro.train.train_step import init_opt_state, make_train_step
+from repro.train.train_step import (
+    init_opt_state,
+    make_train_step,
+    opt_state_specs,
+)
 
 
-def main(argv=None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true")
+def build_parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(prog="repro train")
+    ap.add_argument("--arch", required=True,
+                    help="arch spelling: <arch>, <arch>@reduced[<L>] or "
+                         "<arch>@depth<L>")
     ap.add_argument("--shape", default=None, help="named input shape or none")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
@@ -52,11 +62,13 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt", default="/tmp/repro_train.msgpack")
     ap.add_argument("--ckpt-every", type=int, default=100)
-    args = ap.parse_args(argv)
+    return ap
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+
+def run(args) -> list:
+    """Train for ``args.steps`` steps; returns per-step host floats
+    ``{"loss", "ce", "seconds"}`` (``seconds`` includes step 0's compile)."""
+    cfg = resolve_arch(args.arch)
     if args.shape:
         shape = INPUT_SHAPES[args.shape]
     else:
@@ -100,20 +112,38 @@ def main(argv=None):
         base = registry.init_params(cfg, jax.random.PRNGKey(0))
         params = sharding.to_pipeline_layout(cfg, plan, base)
         opt_state = init_opt_state(cfg, plan, optimizer, params)
+        # lay the state out as the step returns it, so step 1 reuses step
+        # 0's program instead of compiling again for new input shardings
+        on_mesh = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+        params = jax.device_put(params, jax.tree.map(
+            on_mesh, sharding.pipeline_param_specs(cfg, plan)))
+        opt_state = jax.device_put(opt_state, jax.tree.map(
+            on_mesh, opt_state_specs(cfg, plan, optimizer)[1]))
         step_fn = make_train_step(cfg, plan, mesh, optimizer, shape,
                                   bidirectional=not args.uni_ring)
+        history = []
         for i in range(args.steps):
             batch = make_batch(cfg, shape, step=i)
-            t0 = time.time()
+            t0 = time.perf_counter()
             params, opt_state, metrics = step_fn(params, opt_state, batch, i)
-            print(f"step {i:4d} loss={float(metrics['loss']):.4f} "
-                  f"ce={float(metrics['ce']):.4f} ({time.time()-t0:.2f}s)",
-                  flush=True)
+            row = {"loss": float(metrics["loss"]), "ce": float(metrics["ce"])}
+            row["seconds"] = time.perf_counter() - t0
+            history.append(row)
+            print(f"step {i:4d} loss={row['loss']:.4f} ce={row['ce']:.4f} "
+                  f"({row['seconds']:.2f}s)", flush=True)
             if (i + 1) % args.ckpt_every == 0 or fm.should_checkpoint():
                 fm.checkpoint_and_restart((params, opt_state), i + 1)
                 print(f"  checkpointed -> {fm.path}")
     print("done.")
+    return history
+
+
+def main(argv=None) -> None:
+    run(build_parser().parse_args(argv))
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

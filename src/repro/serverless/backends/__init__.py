@@ -45,6 +45,7 @@ from repro.serverless.backends.local import (  # noqa: F401
 from repro.serverless.backends.process import (  # noqa: F401
     ProcessBackend,
     ProcessWorkerHandle,
+    accelerator_conflict,
 )
 
 _REGISTRY: Dict[str, Callable[[], ExecutionBackend]] = {}
@@ -73,7 +74,7 @@ def _availability_of(name: str) -> Optional[str]:
             return "needs POSIX file locks + signals"
         if importlib.util.find_spec("fcntl") is None:  # pragma: no cover
             return "fcntl module missing"
-        return None
+        return accelerator_conflict()
     client = {"aws": "boto3", "oss": "oss2"}.get(name)
     if client is not None and importlib.util.find_spec(client) is None:
         return f"{client} not installed"

@@ -39,6 +39,7 @@ from repro.serverless.backends.base import (
     StepTiming,
     WorkerProgram,
 )
+from repro.serverless.backends.cloud import BackendUnavailableError
 from repro.serverless.backends.local import (
     DEFAULT_GET_TIMEOUT,
     LocalWorkerContext,
@@ -67,6 +68,21 @@ MAX_PROCESSES = 64
 #: extra slack the parent's collect loop grants past the store get timeout
 #: before declaring the step wedged
 _COLLECT_SLACK = 60.0
+
+
+def accelerator_conflict() -> Optional[str]:
+    """None when worker processes may run JAX beside this one; otherwise
+    why not.  An accelerator belongs to one process at a time: a parent
+    whose JAX backend is not the CPU holds the device, so every child that
+    needs it would fail or hang."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return None
+    return (f"this process holds the {platform} device and each worker "
+            "process would need it too, but an accelerator belongs to one "
+            "process at a time; run on 'emulated' or 'local' instead")
 
 
 def _errors_by_name() -> Dict[str, Any]:
@@ -198,6 +214,9 @@ class ProcessBackend(ExecutionBackend):
             raise RuntimeError(
                 "the process backend needs POSIX file locks and signals; "
                 "replay this plan on 'local' or 'emulated' instead")
+        why = accelerator_conflict()
+        if why is not None:
+            raise BackendUnavailableError(f"process backend: {why}")
         if agg.S * agg.d > MAX_PROCESSES:
             raise ValueError(
                 f"plan spawns {agg.S}x{agg.d}={agg.S * agg.d} worker "

@@ -15,7 +15,6 @@ the training planner charges.
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
 from typing import Tuple
 
@@ -32,31 +31,18 @@ TOKEN_BYTES = 4
 def arch_config_for_model(model: str):
     """ArchConfig for a serving model id.
 
-    Mirrors ``repro.core.profiler.resolve_profile``'s spelling — arch ids
-    plus the ``<arch>@reduced[<n_layers>]`` reduced form — but *rejects* the
+    Reads the spellings of ``repro.configs.resolve_arch`` but *rejects* the
     paper's Table 1 models: they are analytic layer tables with no runnable
     layers, and serving needs executable prefill/decode math.
     """
-    from repro.configs import ARCH_IDS, get_config
+    from repro.configs import resolve_arch
 
-    base, _, spec = model.partition("@")
-    if base not in ARCH_IDS or (spec and not spec.startswith("reduced")):
+    try:
+        return resolve_arch(model)
+    except KeyError as e:
         raise KeyError(
-            f"serving needs an executable architecture; {model!r} is not an "
-            "arch id (paper Table 1 models are analytic-only). Use an arch "
-            "id, optionally reduced: '<arch>@reduced[<n_layers>]'")
-    cfg = get_config(base)
-    if spec:
-        cfg = cfg.reduced()
-        depth = spec[len("reduced"):]
-        if depth:
-            try:
-                cfg = dataclasses.replace(cfg, n_layers=int(depth))
-            except ValueError:
-                raise KeyError(
-                    f"malformed reduced-arch spec {model!r}: depth "
-                    f"{depth!r} is not an integer") from None
-    return cfg
+            f"serving needs an executable architecture (paper Table 1 "
+            f"models are analytic-only): {e.args[0]}") from None
 
 
 @dataclass(frozen=True)

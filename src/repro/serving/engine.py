@@ -282,10 +282,11 @@ def run_serve_plan(plan, *, backend: str = "emulated", seed: int = 0,
 
 
 def reference_decode(cfg, params, toks: np.ndarray, n_new: int, *,
-                     s_ctx: Optional[int] = None) -> np.ndarray:
+                     s_ctx: Optional[int] = None, return_logits: bool = False):
     """Monolithic greedy loop (the parity oracle): ``registry.prefill`` +
-    ``registry.decode_step`` on one worker, same sampling rule."""
-    import jax
+    ``registry.decode_step`` on one worker, same sampling rule.  Returns the
+    tokens [B, n_new], and with ``return_logits`` also the float32 logits
+    each token was chosen from [B, n_new, V]."""
     import jax.numpy as jnp
 
     from repro.models import registry
@@ -294,9 +295,13 @@ def reference_decode(cfg, params, toks: np.ndarray, n_new: int, *,
         s_ctx = toks.shape[1] + n_new
     logits, caches = registry.prefill(cfg, params, {"tokens": jnp.asarray(toks)},
                                       capacity=s_ctx)
-    out = [greedy_token(logits)]
+    out, seen = [greedy_token(logits)], [logits[:, -1]]
     for _ in range(1, n_new):
         logits, caches = registry.decode_step(
             cfg, params, caches, jnp.asarray(out[-1]))
         out.append(greedy_token(logits))
+        seen.append(logits[:, -1])
+    if return_logits:
+        return np.hstack(out), np.stack(
+            [np.asarray(lg, np.float32) for lg in seen], axis=1)
     return np.hstack(out)

@@ -2,9 +2,12 @@
 
 Run in a subprocess with fake devices:
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python -m repro.testing.pipeline_equiv [arch_id] [stages] [tensor]
+        python -m repro.testing.pipeline_equiv [model] [stages] [tensor]
 
-Exits nonzero on mismatch.  Used by tests/test_pipeline_multidev.py.
+``model`` is any spelling of ``repro.configs.resolve_arch`` (the tests pass
+``<arch>@reduced[<L>]``); the mesh is every visible device, ``data`` =
+devices / (stages * tensor).  Exits nonzero on mismatch.  Used by
+tests/test_multidev.py.
 """
 import os
 import sys
@@ -20,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import resolve_arch
 from repro.configs.base import InputShape
 from repro.core import sharding
 from repro.core.plan import make_plan
@@ -53,13 +56,11 @@ def reference_step(cfg, base_params, batch, optimizer, step_idx=0):
     return jax.tree.map(upd, grads, base_params), loss, metrics
 
 
-def run(arch_id="phi3-mini-3.8b", stages=4, tensor=1, n_layers=None,
+def run(model="phi3-mini-3.8b@reduced", stages=4, tensor=1,
         bidirectional=True, seed=0, tol=2e-4):
-    data_ax = 8 // (stages * tensor)
+    data_ax = len(jax.devices()) // (stages * tensor)
     mesh = jax.make_mesh((data_ax, stages * tensor), ("data", "model"))
-    cfg = get_config(arch_id).reduced()
-    if n_layers is not None:
-        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    cfg = resolve_arch(model)
     if cfg.moe is not None:
         # capacity: avoid drop mismatches between micro-batch groupings;
         # aux: the load-balance loss is an expectation over the routing group,
@@ -104,7 +105,7 @@ def run(arch_id="phi3-mini-3.8b", stages=4, tensor=1, n_layers=None,
         if e > worst[1]:
             worst = (jax.tree_util.keystr(path), e)
     errs["param"] = worst
-    print(f"[pipeline_equiv] {arch_id} stages={stages} tp={tensor} "
+    print(f"[pipeline_equiv] {model} stages={stages} tp={tensor} "
           f"loss={float(metrics['loss']):.5f} ref={float(ref_loss):.5f} "
           f"loss_err={loss_err:.2e} worst_param={worst[0]} err={worst[1]:.2e}")
     ok = loss_err < tol and worst[1] < tol * 50
@@ -115,9 +116,8 @@ if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser(description="pipeline-vs-monolithic check")
-    ap.add_argument("arch", nargs="?", default="phi3-mini-3.8b")
+    ap.add_argument("model", nargs="?", default="phi3-mini-3.8b@reduced")
     ap.add_argument("stages", nargs="?", type=int, default=4)
     ap.add_argument("tensor", nargs="?", type=int, default=1)
-    ap.add_argument("n_layers", nargs="?", type=int, default=None)
     a = ap.parse_args()
-    sys.exit(0 if run(a.arch, a.stages, a.tensor, a.n_layers) else 1)
+    sys.exit(0 if run(a.model, a.stages, a.tensor) else 1)

@@ -15,6 +15,7 @@ SRC = os.path.join(REPO, "src")
 def run_multidev(module: str, *args: str, devices: int = 8, timeout: int = 1200):
     """Run ``python -m repro.testing.<module> args...`` with fake devices."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"    # fake host devices; never an accelerator
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

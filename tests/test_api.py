@@ -159,6 +159,77 @@ def test_resolve_profile_reduced_arch_spelling():
         resolve_profile("phi3-mini-3.8b@huge", AWS_LAMBDA)
 
 
+def test_depth_spelling_keeps_published_widths():
+    """`@depth<L>` cuts depth only: every published width of phi3 stays and
+    n_layers is the one field that changes."""
+    from repro.configs import get_config, resolve_arch
+
+    full = get_config("phi3-mini-3.8b")
+    cut = resolve_arch("phi3-mini-3.8b@depth2")
+    assert cut == dataclasses.replace(full, n_layers=2)
+    changed = {f.name for f in dataclasses.fields(full)
+               if getattr(full, f.name) != getattr(cut, f.name)}
+    assert changed == {"n_layers"}
+    assert resolve_arch("phi3-mini-3.8b") == full
+
+
+def test_reduced_spelling_unchanged():
+    from repro.configs import get_config, resolve_arch
+
+    red = get_config("qwen2.5-14b").reduced()
+    assert resolve_arch("qwen2.5-14b@reduced") == red
+    assert resolve_arch("qwen2.5-14b@reduced4") == dataclasses.replace(red, n_layers=4)
+
+
+@pytest.mark.parametrize("bad", [
+    "phi3-mini-3.8b@depth0", "phi3-mini-3.8b@depth33",
+    "phi3-mini-3.8b@depthx", "phi3-mini-3.8b@depth", "phi3-mini-3.8b@wide2",
+    "jamba-v0.1-52b@depth4", "bert-large@depth2", "no-such-arch"])
+def test_bad_spellings_raise_key_error(bad):
+    from repro.configs import resolve_arch
+
+    with pytest.raises(KeyError):
+        resolve_arch(bad)
+
+
+def test_depth_plan_round_trips_resolve(tmp_path):
+    """A plan recorded at `@depth2` replays through resolve() to the profile
+    it was built on (fingerprint-checked), also after a JSON round trip."""
+    from repro.api.numeric import numeric_partition
+    from repro.configs import resolve_arch
+    from repro.core.perfmodel import Config
+    from repro.core.profiler import arch_model_profile
+
+    cfg = resolve_arch("phi3-mini-3.8b@depth2")
+    prof = arch_model_profile(cfg, AWS_LAMBDA, seq=512, micro_batch=2)
+    x = numeric_partition(cfg, 2)
+    plan = DeploymentPlan.from_config(
+        prof, AWS_LAMBDA, Config(x=x, d=1, z=(3,) * prof.L), 4,
+        model="phi3-mini-3.8b@depth2", seq=512, micro_batch=2)
+    assert x == (0, 1, 0)
+    path = tmp_path / "depth2.json"
+    plan.save(path)
+    rp = DeploymentPlan.load(path).resolve()
+    assert profile_fingerprint(rp.profile) == profile_fingerprint(prof)
+    assert rp.profile.L == 4
+
+
+def test_numeric_plan_records_a_replayable_spelling():
+    from repro.api import numeric_plan
+
+    plan, prof, ex = numeric_plan("phi3-mini-3.8b@reduced4", stages=2, dp=2,
+                                  batch=8, seq=16)
+    assert plan.model == "phi3-mini-3.8b@reduced4"
+    assert (plan.n_stages, plan.d, plan.total_micro_batches) == (2, 2, 4)
+    assert ex.cfg.n_layers == 4 and ex.cfg.d_model <= 256
+    rp = plan.resolve()               # rebuilt from the recorded spelling
+    assert profile_fingerprint(rp.profile) == profile_fingerprint(prof)
+    with pytest.raises(ValueError, match="stages"):
+        numeric_plan("phi3-mini-3.8b@reduced4", stages=5, dp=1, batch=8)
+    with pytest.raises(ValueError, match="divisible"):
+        numeric_plan("phi3-mini-3.8b@reduced4", stages=2, dp=3, batch=8)
+
+
 def test_fingerprint_tracks_profile_content():
     a = paper_model_profile("bert-large", AWS_LAMBDA)
     b = paper_model_profile("bert-large", AWS_LAMBDA)

@@ -88,15 +88,15 @@ def _chaos_plan():
 _REFERENCE = {}
 
 
-def _fault_free_params(pipelined):
-    """Fault-free reference params (cached; emulated — backend parity of the
-    clean run is test_backends' business)."""
+def _fault_free_run(pipelined):
+    """Fault-free reference (params, losses), cached; emulated — backend
+    parity of the clean run is test_backends' business."""
     if pipelined not in _REFERENCE:
         _, prof, config, _, _, _, mk_exec = _numeric_setup(steps=3)
         res = run_plan(prof, AWS_LAMBDA, config, 4, steps=3,
                        pipelined_sync=pipelined, execution=mk_exec(),
                        backend="emulated")
-        _REFERENCE[pipelined] = res.params
+        _REFERENCE[pipelined] = (res.params, res.losses)
     return _REFERENCE[pipelined]
 
 
@@ -125,10 +125,10 @@ def test_chaos_run_recovers_bit_identical(backend, pipelined):
     assert rep.retries >= 1
     assert rep.restarts + rep.planned_restarts >= 2   # crash + lifetime cap
     assert rep.checkpoints >= 1
-    _assert_bit_identical(res.params, _fault_free_params(pipelined))
+    params, losses = _fault_free_run(pipelined)
+    _assert_bit_identical(res.params, params)
     # losses replayed identically too (run_plan verified drained internally)
-    assert [m["loss"] for m in res.metrics] == pytest.approx(
-        [6.9599, 6.6724, 4.5243], abs=1e-3)
+    assert res.losses == losses
 
 
 def test_chaos_report_identical_across_backends():
@@ -239,7 +239,7 @@ def test_straggler_slows_but_does_not_change_numbers():
                    backend="emulated", faults=plan)
     assert res.fault_report.injected == {"straggle": 1}
     assert res.fault_report.restarts == 0
-    _assert_bit_identical(res.params, _fault_free_params(True))
+    _assert_bit_identical(res.params, _fault_free_run(True)[0])
 
 
 # -------------------------------------------------- recovery observability

@@ -84,6 +84,50 @@ def test_swiglu(T, d, f, dtype):
                                np.asarray(expect, np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("n,pref,align,want", [
+    (512, 128, 8, 128), (520, 128, 8, 104), (96, 128, 8, 96),
+    (1000, 256, 8, 200), (3072, 512, 128, 512), (3200, 512, 128, 128),
+    (1100, 512, 128, None)])
+def test_ops_block_divides_the_shape(n, pref, align, want):
+    from repro.kernels import ops
+
+    assert ops._block(n, pref, align) == want
+
+
+def test_ops_non_dividing_shapes_run_the_kernel(monkeypatch):
+    """Shapes the default tiles do not divide get a tile that does (S=520,
+    T=1000) instead of tripping the kernels' divisibility asserts."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    from repro.kernels import ops
+
+    key = jax.random.PRNGKey(1)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, 520, 2, 64))
+               for i in range(3))
+    np.testing.assert_allclose(
+        np.asarray(ops.flash_attention(q, k, v)),
+        np.asarray(ref.flash_attention_ref(q, k, v)), rtol=2e-5, atol=2e-5)
+    x = jax.random.normal(key, (1000, 256))
+    wg = 0.05 * jax.random.normal(jax.random.fold_in(key, 4), (256, 384))
+    wu = 0.05 * jax.random.normal(jax.random.fold_in(key, 5), (256, 384))
+    np.testing.assert_allclose(np.asarray(ops.swiglu(x, wg, wu)),
+                               np.asarray(ref.swiglu_ref(x, wg, wu)),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_ops_refuses_interpret_on_tpu(monkeypatch):
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpreter on the TPU"):
+        ops.kernel_mode()
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "auto")
+    assert ops.kernel_mode() == "pallas"
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "fast")
+    with pytest.raises(ValueError, match="expected one of"):
+        ops.kernel_mode()
+
+
 def test_ops_dispatch_ref_mode(monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
     from repro.kernels import ops

@@ -1,38 +1,7 @@
 """Multi-device integration tests.  Each runs a repro.testing.* module in a
 subprocess with 8 fake CPU devices so this pytest process keeps seeing 1
 device (dry-run isolation rule)."""
-import inspect
-
 import pytest
-
-
-def _multidev_missing_apis():
-    """The repro.testing harness modules target the modern mesh/shard_map
-    surface; probe for it instead of failing 13 tests on older jax."""
-    import jax
-
-    missing = []
-    if not hasattr(jax.sharding, "AxisType"):
-        missing.append("jax.sharding.AxisType")
-    if not hasattr(jax, "set_mesh"):
-        missing.append("jax.set_mesh")
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        missing.append("jax.shard_map")
-    else:
-        try:
-            if "check_vma" not in inspect.signature(sm).parameters:
-                missing.append("jax.shard_map(check_vma=)")
-        except (TypeError, ValueError):
-            pass
-    return missing
-
-
-_MISSING = _multidev_missing_apis()
-pytestmark = pytest.mark.skipif(
-    bool(_MISSING),
-    reason="repro.testing multidev modules need "
-           f"{', '.join(_MISSING)} (newer jax required)")
 
 
 def test_ring_collectives(multidev):
@@ -50,8 +19,8 @@ def test_ring_collectives(multidev):
 ])
 def test_pipeline_train_equivalence(multidev, arch, stages, tensor, layers):
     """Pipelined train step == single-device step (loss + updated params)."""
-    args = [arch, stages, tensor] + ([] if layers == "none" else [layers])
-    out = multidev("pipeline_equiv", *args)
+    depth = "" if layers == "none" else layers
+    out = multidev("pipeline_equiv", f"{arch}@reduced{depth}", stages, tensor)
     assert "loss_err" in out
 
 

@@ -240,6 +240,29 @@ def test_process_backend_registered_and_available():
     assert avail["emulated"] is None and avail["local"] is None
 
 
+def test_process_backend_refuses_an_accelerator_host(monkeypatch, tmp_path):
+    """One process per chip: a parent holding a TPU must not spawn workers
+    that need it too — open() refuses before spawning anything."""
+    import jax
+
+    from repro.core.partition import merge_layers
+    from repro.core.perfmodel import Config
+    from repro.core.profiler import paper_model_profile
+    from repro.serverless.backends import BackendUnavailableError
+    from repro.serverless.platform import AWS_LAMBDA
+    from repro.serverless.simulator import stage_aggregates
+
+    prof = merge_layers(paper_model_profile("bert-large", AWS_LAMBDA), 4)
+    config = Config(x=(0, 1, 0), d=1, z=(0,) * 4)
+    agg = stage_aggregates(prof, AWS_LAMBDA, config, 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    be = ProcessBackend(root=str(tmp_path / "store"))
+    with pytest.raises(BackendUnavailableError, match="one process at a time"):
+        be.open(agg)
+    assert not be._procs
+    assert "tpu device" in backend_availability()["process"]
+
+
 def test_unknown_backend_error_lists_names_and_availability():
     with pytest.raises(KeyError) as ei:
         get_backend("s3-but-misspelled")
