@@ -5,10 +5,12 @@ Times ``run_plan`` on the **local** execution backend (real daemon threads
 over the blocking in-process store — the only backend where host wall-clock
 is the measurement, so recording overhead is observable) in three modes:
 
-* ``off``      — no recorder attached; the per-op cost is one
-  ``tracer is None`` check,
+* ``off``      — no recorder attached; each op still calls
+  ``repro.obs.open_span``, which returns the shared no-op ``UNTRACED``
+  context (no annotation, no clock read), and enters and leaves it,
 * ``on``       — ``trace=True``: every store op and compute block brackets a
-  ``perf_counter`` pair and appends a Span,
+  ``perf_counter`` pair inside a ``jax.profiler.TraceAnnotation`` and
+  appends a Span (``repro.obs.open_span``),
 * ``emulated`` — the virtual-clock backend traced, as a sanity row (its
   "overhead" is pure bookkeeping; the virtual timings are identical by
   construction).

@@ -602,6 +602,9 @@ def _cmd_inspect(args) -> int:
               f"{rcv['restart_count']} restore reads "
               f"({rcv['restart_s']:.3f}s, "
               f"{rcv['restart_bytes'] / MB:.0f}MB re-fetched)")
+    for op, row in (h.get("host") or {}).items():
+        print(f"host {op}: {row['count']} spans, {row['s']:.3f}s, "
+              f"{row['bytes'] / MB:.0f}MB")
     for phase in ("fwd", "bwd", "sync"):
         pb = h["phase_bytes"].get(phase)
         if pb:

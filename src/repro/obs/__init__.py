@@ -26,6 +26,7 @@ from repro.obs.calibrate import (
 )
 from repro.obs.metrics import pipeline_health
 from repro.obs.schema import (
+    HOST_OPS,
     OPS,
     PHASES,
     RESOURCE_OF,
@@ -34,13 +35,15 @@ from repro.obs.schema import (
     Trace,
     TraceValidationError,
     WorkerTracer,
+    open_span,
     validate_trace,
 )
 
 __all__ = [
     "ELAPSED", "GapRow", "gap_attribution", "pipeline_health",
-    "OPS", "PHASES", "RESOURCE_OF", "Span", "SpanRecorder", "Trace",
-    "TraceValidationError", "WorkerTracer", "validate_trace",
+    "HOST_OPS", "OPS", "PHASES", "RESOURCE_OF", "Span", "SpanRecorder",
+    "Trace", "TraceValidationError", "WorkerTracer", "open_span",
+    "validate_trace",
     "Calibration", "PerfModelWarning", "ReplanReport", "StageObservation",
     "calibrate_profile", "calibrate_trace", "observe_stages", "replan",
     "stage_prediction_errors",

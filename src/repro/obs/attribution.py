@@ -16,6 +16,11 @@ differenced directly:
   (serialization the simulator missed); elapsed catches that.  The sync
   phase is compared on elapsed only: observed sync is per-chunk transfers,
   predicted sync is one closed-form interval.
+* **host cells** — a numeric wall-clock run's host work at the sync boundary
+  (``pack``, ``update``: ``repro.obs.HOST_OPS``) gets busy rows of its own
+  with predicted 0, and stays out of the sync makespan: the perf model has
+  no term for it, so its whole cost shows as gap instead of hiding in the
+  collective's row.
 
 Rows are ranked by absolute gap — the top row is where the simulator and
 the runtime disagree most, i.e. where the roofline/1F1B work should look
@@ -27,7 +32,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
-from repro.obs.schema import Span, Trace
+from repro.obs.schema import HOST_OPS, Span, Trace
 
 ELAPSED = "(elapsed)"
 
@@ -65,7 +70,7 @@ def _elapsed_cells(spans: List[Span]) -> Dict[Tuple[int, str], float]:
     """Phase makespan per (stage, phase), averaged over (replica, step)."""
     extent: Dict[tuple, Tuple[float, float]] = {}
     for s in spans:
-        if s.op == "barrier":
+        if s.op == "barrier" or s.op in HOST_OPS:
             continue
         k = (s.stage, s.phase, s.replica, s.step)
         lo, hi = extent.get(k, (s.start, s.end))
@@ -99,7 +104,7 @@ def gap_attribution(trace: Trace,
     obs = _busy_cells(trace.spans)
     pred = _busy_cells(predicted)
     for (stage, phase, op) in sorted(set(obs) | set(pred)):
-        if phase == "sync":
+        if phase == "sync" and op not in HOST_OPS:
             continue           # per-chunk vs closed-form: elapsed-only below
         rows.append(GapRow(stage=stage, phase=phase, op=op,
                            observed_s=obs.get((stage, phase, op), 0.0) / norm,

@@ -14,6 +14,10 @@ Definitions (also in README "Observability"):
 * **straggler ratio**: max over workers of total busy time divided by the
   mean — 1.0 is perfectly balanced; the paper's symmetric stages should sit
   near 1 on the virtual clock, while wall-clock runs expose host jitter.
+* **host work** (numeric wall-clock runs): seconds, count and bytes of the
+  ``pack``/``update`` spans (``repro.obs.HOST_OPS``), the workers' host work
+  at the sync boundary.  ``compute_frac`` counts ``compute`` spans only,
+  so a stage's ``bubble_frac`` (``1 - compute_frac``) includes its host work.
 * **phase byte totals**: uploaded/downloaded bytes per (phase, direction),
   reconciled against the store's own ``StoreStats`` counters — the span
   layer and the byte-accounting layer must tell the same story.
@@ -22,7 +26,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List
 
-from repro.obs.schema import Trace
+from repro.obs.schema import HOST_OPS, Trace
 
 
 def pipeline_health(trace: Trace) -> Dict[str, Any]:
@@ -47,7 +51,7 @@ def pipeline_health(trace: Trace) -> Dict[str, Any]:
         nbytes = {"uplink": 0.0, "downlink": 0.0}
         for sp in mine:
             res = sp.resource
-            if res is not None:
+            if res is not None and sp.op not in HOST_OPS:
                 busy[res] += sp.duration
                 if res != "cpu":
                     nbytes[res] += sp.nbytes
@@ -88,6 +92,13 @@ def pipeline_health(trace: Trace) -> Dict[str, Any]:
         "straggler_ratio": straggler,
         "phase_bytes": phase_bytes,
     }
+
+    by_op = {op: [sp for sp in spans if sp.op == op] for op in HOST_OPS}
+    if any(by_op.values()):
+        out["host"] = {op: {"s": sum(sp.duration for sp in mine),
+                            "count": len(mine),
+                            "bytes": sum(sp.nbytes for sp in mine)}
+                       for op, mine in by_op.items()}
 
     # recovery overhead: retry-backoff stalls and checkpoint-restore reads
     # (the fault-tolerance layer's footprint on the timeline; zero on a

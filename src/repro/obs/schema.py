@@ -10,7 +10,11 @@ occupied.  The *same* schema carries three kinds of timelines:
   * **virtual** spans from the emulated backend (``StageChannel`` emits one
     span per charged resource task, including every scatter-reduce chunk);
   * **wall** spans from the local backend's real threads (host
-    ``perf_counter`` intervals around the blocking store ops);
+    ``perf_counter`` intervals around the blocking store ops, the compute
+    calls, and the host work at the sync boundary: ``pack`` copies the
+    gradient to the host, ``update`` applies the reduced one).  Each is also
+    a ``jax.profiler`` host event named ``s{stage}r{replica} {phase}.{op}``
+    (see :func:`open_span`), so a profiler trace shows them on its clock;
   * **predicted** spans from ``simulate_funcpipe``'s longest-path DP — the
     simulator's opinion of where each op should land, in the same shape, so
     ``repro.obs.attribution`` can difference them cell by cell.
@@ -32,13 +36,20 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 # fwd/bwd/sync are the training phases (ordering-checked below); prefill and
 # decode are the serving engine's phases — serving traces have no intra-step
 # phase-order invariant beyond lane occupancy
 PHASES = ("fwd", "bwd", "sync", "prefill", "decode")
-OPS = ("download", "compute", "upload", "barrier", "sync", "retry", "restart")
+OPS = ("download", "compute", "upload", "barrier", "sync", "retry", "restart",
+       "pack", "update")
+
+# a worker's host work at the sync boundary (phase "sync", the cpu lane):
+# "pack" copies the accumulated gradient to the host as one flat vector,
+# "update" applies the reduced vector (host -> device, optimizer step).
+# The perf model has no term for either.
+HOST_OPS = ("pack", "update")
 
 # which serial worker resource a span occupies; barrier and the closed-form
 # sync interval are ordering/aggregate marks, not resource occupancy.
@@ -53,6 +64,8 @@ RESOURCE_OF = {
     "sync": None,
     "retry": None,
     "restart": None,
+    "pack": "cpu",
+    "update": "cpu",
 }
 
 
@@ -69,10 +82,13 @@ class Span:
     replica: int
     step: int
     phase: str                  # fwd | bwd | sync
-    op: str                     # download | compute | upload | barrier | sync
+    op: str                     # one of OPS
     start: float
     end: float
-    nbytes: float = 0.0         # modeled object size (transfers), else 0
+    # transfers: the modeled object size; pack/update: the bytes of the host
+    # gradient vector that really moved (device -> host, host -> device);
+    # else 0
+    nbytes: float = 0.0
     key: Optional[str] = None   # store key (transfers), else None
 
     @property
@@ -107,9 +123,9 @@ class Span:
 
 class WorkerTracer:
     """One worker's span emitter: bound to a (stage, replica), carrying the
-    mutable step/phase state the backend driver keeps current.  ``emit`` is
-    the only hot-path call; backends guard it with ``if tracer is not None``
-    so untraced runs pay nothing."""
+    mutable step/phase state its backend keeps current.  Wall-clock
+    backends emit through :func:`open_span`; untraced runs hold no tracer
+    and pay nothing."""
 
     __slots__ = ("_spans", "stage", "replica", "step", "phase")
 
@@ -126,6 +142,77 @@ class WorkerTracer:
             stage=self.stage, replica=self.replica, step=self.step,
             phase=self.phase, op=op, start=float(start), end=float(end),
             nbytes=float(nbytes), key=key))
+
+
+class _OpenSpan:
+    """The context :func:`open_span` returns for a traced worker."""
+
+    __slots__ = ("tracer", "clock", "op", "nbytes", "key", "t0", "_ann")
+
+    def __init__(self, tracer: WorkerTracer, clock: Callable[[], float],
+                 op: str, nbytes: float, key: Optional[str]):
+        self.tracer = tracer
+        self.clock = clock
+        self.op = op
+        self.nbytes = nbytes
+        self.key = key
+
+    def __enter__(self) -> "_OpenSpan":
+        from jax.profiler import TraceAnnotation
+
+        tr = self.tracer
+        self._ann = TraceAnnotation(
+            f"s{tr.stage}r{tr.replica} {tr.phase}.{self.op}", step=tr.step)
+        self._ann.__enter__()
+        self.t0 = self.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = self.clock()
+        self._ann.__exit__(*exc)
+        if exc[0] is None:          # a failed op leaves no span, as before
+            self.tracer.emit(self.op, self.t0, t1, nbytes=self.nbytes,
+                             key=self.key)
+
+
+class _Untraced:
+    """The shared do-nothing context of untraced workers: it holds no state,
+    and a write to ``nbytes`` is dropped."""
+
+    __slots__ = ()
+
+    @property
+    def nbytes(self) -> float:
+        return 0.0
+
+    @nbytes.setter
+    def nbytes(self, _value) -> None:
+        pass
+
+    def __enter__(self) -> "_Untraced":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+UNTRACED = _Untraced()
+
+
+def open_span(tracer: Optional[WorkerTracer],
+              clock: Optional[Callable[[], float]], op: str, *,
+              nbytes: float = 0.0, key: Optional[str] = None):
+    """``with open_span(tracer, clock, op) as sp: ...`` records the body as
+    one ``op`` span of the tracer's worker, in its current step and phase,
+    on ``clock``; the body may set ``sp.nbytes`` once it knows the size.
+    While the body runs, a ``jax.profiler.TraceAnnotation`` named
+    ``s{stage}r{replica} {phase}.{op}`` (argument ``step``) is open on the
+    calling thread, so a profiler session records the span as a host event
+    on the device trace's clock.  With no tracer (or no clock) it is
+    :data:`UNTRACED`: no annotation, no clock read."""
+    if tracer is None or clock is None:
+        return UNTRACED
+    return _OpenSpan(tracer, clock, op, nbytes, key)
 
 
 class SpanRecorder:

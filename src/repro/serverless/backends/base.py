@@ -89,6 +89,17 @@ class WorkerContext(ABC):
         done (virtual clocks must model this; real serial workers get it
         for free)."""
 
+    def host(self, op: str, fn: Callable[[], Any], *,
+             nbytes: Optional[float] = None) -> Any:
+        """Run the worker's host work ``fn`` at the sync boundary (``op``:
+        ``"pack"``, the gradient to the host; ``"update"``, the reduced
+        gradient applied) and return its result.  Wall-clock backends keep
+        the worker's lease alive around it and, when traced, record it as one
+        ``op`` span of phase ``sync`` whose ``nbytes`` is ``nbytes``, or the
+        ``.nbytes`` of what ``fn`` returns.  The default runs ``fn`` and
+        charges nothing: the cost model has no term for this work."""
+        return fn()
+
     def wait(self, seconds: float, op: str = "retry") -> None:
         """Charge ``seconds`` of idle occupancy on this worker (retry
         backoff, injected straggle).  Virtual clocks stall the worker's

@@ -30,6 +30,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.obs.schema import open_span
 from repro.serverless.backends.base import (
     ExecutionBackend,
     StepTiming,
@@ -291,9 +292,10 @@ class LocalWorkerContext(WorkerContext):
     """A stage worker on a real thread: blocking store, no modeled clock.
 
     With ``tracer``/``clock`` set (``repro.obs.WorkerTracer`` + seconds since
-    run start), every store op and compute emits one *wall-clock* span; a
-    blocking download's visibility wait is part of its span, which is exactly
-    the stall the timeline should show.
+    run start), every store op, compute and host step emits one *wall-clock*
+    span (``repro.obs.open_span``, which also marks it in a running
+    ``jax.profiler`` trace); a blocking download's visibility wait is part
+    of its span, which is exactly the stall the timeline should show.
     """
 
     def __init__(self, store: LocalStore, tracer=None, clock=None,
@@ -307,35 +309,43 @@ class LocalWorkerContext(WorkerContext):
         if self.worker is not None:
             self.store.heartbeat(self.worker)
 
+    def _span(self, op: str, *, nbytes: float = 0.0,
+              key: Optional[str] = None):
+        return open_span(self.tracer, self.clock, op, nbytes=nbytes, key=key)
+
     def download(self, key: str):
         self._beat()
-        if self.tracer is None:
-            return self.store.take(key), None
-        t0 = self.clock()
-        value, nb = self.store.take(key, return_nbytes=True)
-        self.tracer.emit("download", t0, self.clock(), nbytes=nb, key=key)
+        with self._span("download", key=key) as sp:
+            value, sp.nbytes = self.store.take(key, return_nbytes=True)
         return value, None
 
     def compute(self, cost_s: float, fn: Optional[Callable[[], Any]] = None,
                 after: Any = None) -> Any:
         # modeled cost is the virtual clock's business; here compute is real
         self._beat()
-        if self.tracer is None:
+        with self._span("compute"):
             return fn() if fn is not None else None
-        t0 = self.clock()
-        out = fn() if fn is not None else None
-        self.tracer.emit("compute", t0, self.clock())
-        return out
 
     def upload(self, key: str, nbytes: float, value: Any = None) -> Any:
         self._beat()
-        if self.tracer is None:
+        with self._span("upload", nbytes=nbytes, key=key):
             self.store.put(key, nbytes, value=value)
-            return None
-        t0 = self.clock()
-        self.store.put(key, nbytes, value=value)
-        self.tracer.emit("upload", t0, self.clock(), nbytes=nbytes, key=key)
         return None
+
+    def host(self, op: str, fn: Callable[[], Any], *,
+             nbytes: Optional[float] = None) -> Any:
+        # host work belongs to the sync phase: for tracing, this is the
+        # worker's bwd -> sync flip (its sync yield comes later)
+        self._beat()
+        if self.tracer is not None:
+            self.tracer.phase = "sync"
+        with self._span(op) as sp:
+            out = fn()
+            sp.nbytes = getattr(out, "nbytes", 0.0) if nbytes is None else nbytes
+        # beat again: the next step's consumers may check this lease before
+        # the worker's first op of that step, and the update can outlast it
+        self._beat()
+        return out
 
     def phase_barrier(self) -> None:
         # a serial worker's forward uploads complete before it proceeds;
@@ -349,21 +359,14 @@ class LocalWorkerContext(WorkerContext):
         # real backoff on the wall-clock backend (the time is honest, and
         # the op span makes recovery overhead visible in the trace)
         self._beat()
-        if self.tracer is None:
+        with self._span(op):
             time.sleep(seconds)
-            return
-        t0 = self.clock()
-        time.sleep(seconds)
-        self.tracer.emit(op, t0, self.clock())
 
     def fetch(self, key: str, op: str = "download"):
         # non-consuming blocking get (checkpoint restore)
         self._beat()
-        if self.tracer is None:
-            return self.store.get(key), None
-        t0 = self.clock()
-        value, nb = self.store.get(key, return_nbytes=True)
-        self.tracer.emit(op, t0, self.clock(), nbytes=nb, key=key)
+        with self._span(op, key=key) as sp:
+            value, sp.nbytes = self.store.get(key, return_nbytes=True)
         return value, None
 
 

@@ -414,6 +414,11 @@ class FaultyWorkerContext(WorkerContext):
         self.phase = "bwd"
         self._check_liveness()          # bwd-phase crashes fire at the fence
 
+    def host(self, op: str, fn: Callable[[], Any], *,
+             nbytes: Optional[float] = None) -> Any:
+        # no injection point: the host work mutates the worker's state
+        return self.inner.host(op, fn, nbytes=nbytes)
+
     def wait(self, seconds: float, op: str = "retry") -> None:
         self.inner.wait(seconds, op=op)
 
@@ -551,6 +556,11 @@ class ResilientContext(WorkerContext):
 
     def phase_barrier(self) -> None:
         self.inner.phase_barrier()
+
+    def host(self, op: str, fn: Callable[[], Any], *,
+             nbytes: Optional[float] = None) -> Any:
+        # never retried: the host work mutates the worker's state
+        return self.inner.host(op, fn, nbytes=nbytes)
 
     def wait(self, seconds: float, op: str = "retry") -> None:
         self.inner.wait(seconds, op=op)

@@ -119,7 +119,10 @@ def _worker_step_program(ctx: WorkerContext, *, k: int, s: int, r: int, agg,
     each op group so virtual-clock drivers can interleave workers), the
     fwd/bwd phase fence, ``mu`` backwards in reverse order, then a
     ``("sync", grad_vector)`` yield answered by the backend with the reduced
-    gradient, from which the worker applies its optimizer update.
+    gradient, from which the worker applies its optimizer update.  Packing
+    the gradient and applying the update are the worker's host work
+    (``ctx.host``: ``"pack"`` and ``"update"`` spans on traced wall-clock
+    backends); timing-only plans (no ``worker``) have neither.
     """
     S, mu, d = agg.S, agg.mu, agg.d
     ce_acc = 0.0
@@ -164,11 +167,14 @@ def _worker_step_program(ctx: WorkerContext, *, k: int, s: int, r: int, agg,
         yield
 
     # ------------------------------------------------------------------- sync
-    vec = worker.grad_vector() if worker is not None else None
+    if worker is None:
+        yield ("sync", None)
+        return
+    vec = ctx.host("pack", worker.grad_vector)
     reduced = yield ("sync", vec)
-    if worker is not None:
-        worker.apply_update(reduced / d, step=k)
-        losses[(s, r)] = (ce_acc, aux_acc)
+    ctx.host("update", lambda: worker.apply_update(reduced / d, step=k),
+             nbytes=reduced.nbytes)
+    losses[(s, r)] = (ce_acc, aux_acc)
 
 
 def run_plan(

@@ -32,6 +32,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.schema import open_span
 from repro.serverless.runtime.store import ObjectStore, StageChannel
 
 
@@ -163,39 +164,27 @@ def local_scatter_reduce(
 
     With ``tracer``/``clock`` set (``repro.obs.WorkerTracer`` + a seconds
     clock), every per-chunk put/take/get and barrier wait emits one
-    wall-clock span — the local mirror of the emulated collectives' per-chunk
-    channel spans.
+    wall-clock span through ``repro.obs.open_span`` — the local mirror of
+    the emulated collectives' per-chunk channel spans.
     """
     i = index
     if n == 1:
         return None if value is None else np.asarray(value, dtype=np.float32)
-    trace_on = tracer is not None and clock is not None
 
-    def _traced_put(key, val):
-        if not trace_on:
+    def put(key, val):
+        with open_span(tracer, clock, "upload", nbytes=chunk_b, key=key):
             store.put(key, chunk_b, value=val)
-            return
-        t0 = clock()
-        store.put(key, chunk_b, value=val)
-        tracer.emit("upload", t0, clock(), nbytes=chunk_b, key=key)
 
-    def _traced_fetch(fetch, key):
-        if not trace_on:
-            return fetch(key)
-        # the blocking visibility wait is inside fetch(); the span covers it,
-        # matching the emulated download span which starts at data-ready
-        t0 = clock()
-        val, nb = fetch(key, True)
-        tracer.emit("download", t0, clock(), nbytes=nb, key=key)
+    def fetch(fetch_fn, key):
+        # the blocking visibility wait is inside fetch_fn(); the span covers
+        # it, matching the emulated download span which starts at data-ready
+        with open_span(tracer, clock, "download", key=key) as sp:
+            val, sp.nbytes = fetch_fn(key, True)
         return val
 
-    def _traced_wait(b):
-        if not trace_on:
+    def wait(b):
+        with open_span(tracer, clock, "barrier"):
             b.wait()
-            return
-        t0 = clock()
-        b.wait()
-        tracer.emit("barrier", t0, clock())
 
     chunk_b = nbytes / n
     chunks = None if value is None else np.array_split(np.asarray(value), n)
@@ -203,28 +192,27 @@ def local_scatter_reduce(
     # scatter: upload my partials of everyone else's chunk, staggered order
     for r in range(1, n):
         j = (i + r) % n
-        _traced_put(f"{key_prefix}/part/{j}/{i}",
-                    None if chunks is None else chunks[j])
+        put(f"{key_prefix}/part/{j}/{i}", None if chunks is None else chunks[j])
     if not pipelined and barrier is not None:
-        _traced_wait(barrier)             # eq (1) phase-1 barrier
+        wait(barrier)                     # eq (1) phase-1 barrier
 
     # reduce: pull the n-1 partials of the owned chunk (blocking as they
     # surface), reduce in ring order, publish the reduced chunk
-    parts = [_traced_fetch(store.take, f"{key_prefix}/part/{i}/{(i - r) % n}")
+    parts = [fetch(store.take, f"{key_prefix}/part/{i}/{(i - r) % n}")
              for r in range(1, n)]
     reduced_i = None if chunks is None else ring_reduce(chunks[i], parts)
-    _traced_put(f"{key_prefix}/red/{i}", reduced_i)
+    put(f"{key_prefix}/red/{i}", reduced_i)
     if not pipelined and barrier is not None:
-        _traced_wait(barrier)             # eq (1) phase-2 barrier
+        wait(barrier)                     # eq (1) phase-2 barrier
 
     # all-gather: pull the other reduced chunks
     out: List[Optional[np.ndarray]] = [None] * n
     out[i] = reduced_i
     for r in range(1, n):
         src = (i + r) % n
-        out[src] = _traced_fetch(store.get, f"{key_prefix}/red/{src}")
+        out[src] = fetch(store.get, f"{key_prefix}/red/{src}")
     if barrier is not None:
-        _traced_wait(barrier)             # cleanup fence: all peers have read
+        wait(barrier)                     # cleanup fence: all peers have read
     store.delete(f"{key_prefix}/red/{i}")
     return None if chunks is None else np.concatenate(out)
 

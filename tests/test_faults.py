@@ -357,6 +357,40 @@ def test_local_store_stale_heartbeat_fails_fast():
     assert time.monotonic() - t0 < 5.0
 
 
+def test_lease_outlives_a_long_optimizer_update(monkeypatch):
+    """A worker's lease is renewed when its update returns: an update that
+    outlasts the lease (a loaded host, the eager optimizer's first-step
+    compiles), then a thread slow to start the next step, must not read
+    as a dead producer to the next stage's first download."""
+    from repro.api import ExecutionConfig, numeric_plan
+    from repro.serverless.backends.local import (
+        DEFAULT_LEASE_TIMEOUT,
+        LocalBackend,
+    )
+    from repro.serverless.runtime import engine
+    from repro.serverless.runtime.worker import StageWorker
+
+    apply_update, split = StageWorker.apply_update, engine._split_batch
+
+    def slow_update(self, reduced, step):
+        apply_update(self, reduced, step)
+        if self.span.index == 0 and step == 0:
+            time.sleep(DEFAULT_LEASE_TIMEOUT + 0.5)
+
+    def late_start(batch, r, d, m, mu):
+        if m == 0:
+            time.sleep(0.5)              # before the worker's first op
+        return split(batch, r, d, m, mu)
+
+    monkeypatch.setattr(StageWorker, "apply_update", slow_update)
+    monkeypatch.setattr(engine, "_split_batch", late_start)
+    plan, prof, ex = numeric_plan("phi3-mini-3.8b@reduced", stages=2, dp=1,
+                                  batch=8, seq=16)
+    res = plan.emulate(ExecutionConfig(backend=LocalBackend(), steps=2),
+                       execution=ex, profile=prof)
+    assert len(res.losses) == 2
+
+
 def test_local_store_abort_wakes_blocked_consumers():
     store = LocalStore(timeout=30.0)
     errs = []

@@ -276,6 +276,28 @@ def test_cli_trace_and_inspect(tmp_path, capsys):
     assert "byte reconciliation vs StoreStats: OK" in out
 
 
+def test_cli_inspect_lists_host_work_of_a_local_numeric_trace(tmp_path,
+                                                              capsys):
+    from repro.cli import main as cli_main
+
+    trace = tmp_path / "t.json"
+    rc = cli_main(["emulate", "--numerics", "--model",
+                   "phi3-mini-3.8b@reduced", "--stages", "2", "--dp", "1",
+                   "--batch", "8", "--seq", "16", "--steps", "2",
+                   "--backend", "local", "--trace", str(trace),
+                   "--no-plan-cache"])
+    capsys.readouterr()
+    assert rc == 0
+    rc = cli_main(["inspect", str(trace)])
+    out = capsys.readouterr().out
+    assert rc == 0 and "trace OK" in out
+    assert "host pack: 4 spans" in out and "host update: 4 spans" in out
+    # observed host work against the model's predicted 0
+    rows = [line.split() for line in out.splitlines()
+            if line.split()[1:3] in (["sync", "pack"], ["sync", "update"])]
+    assert rows and all(float(r[4]) == 0.0 for r in rows)
+
+
 def test_cli_inspect_rejects_invalid(tmp_path, capsys):
     from repro.cli import main as cli_main
 
@@ -283,3 +305,149 @@ def test_cli_inspect_rejects_invalid(tmp_path, capsys):
     bad.write_text("[]")
     with pytest.raises(SystemExit, match="not a repro trace"):
         cli_main(["inspect", str(bad)])
+
+
+# ------------------------------------- host work at the sync boundary (local)
+def _numeric_local(d, *, trace, steps=2):
+    from repro.api import ExecutionConfig, numeric_plan
+
+    plan, prof, ex = numeric_plan("phi3-mini-3.8b@reduced", stages=2, dp=d,
+                                  batch=8, seq=16)
+    res = plan.emulate(ExecutionConfig(backend="local", steps=steps,
+                                       trace=trace),
+                       execution=ex, profile=prof)
+    return plan, ex, res
+
+
+def _stage_param_counts(plan, ex):
+    import jax
+
+    from repro.serverless.runtime.worker import (
+        StageWorker,
+        stage_instance_ranges,
+    )
+
+    return [sum(int(a.size) for a in jax.tree.leaves(
+                StageWorker(ex.cfg, span, ex.init_params, mu=1,
+                            optimizer=ex.optimizer).params))
+            for span in stage_instance_ranges(ex.cfg, plan.config.x)]
+
+
+@pytest.fixture(scope="module", params=[1, 2], ids=["d1", "d2"])
+def numeric_local_runs(request):
+    d = request.param
+    plan, ex, traced = _numeric_local(d, trace=True)
+    _, _, plain = _numeric_local(d, trace=False)
+    return d, plan, ex, traced, plain
+
+
+def test_local_numeric_trace_has_pack_then_update_per_worker_step(
+        numeric_local_runs):
+    d, plan, ex, res, _ = numeric_local_runs
+    tr = res.trace
+    validate_trace(tr)
+    counts = _stage_param_counts(plan, ex)
+    for s in range(2):
+        for r in range(d):
+            for k in range(2):
+                mine = sorted((sp for sp in tr.spans if (sp.stage, sp.replica,
+                               sp.step) == (s, r, k)),
+                              key=lambda sp: sp.start)
+                host = [sp for sp in mine if sp.op in ("pack", "update")]
+                assert [sp.op for sp in host] == ["pack", "update"]
+                assert all(sp.phase == "sync" for sp in host)
+                last_bwd = max(sp.end for sp in mine if sp.phase == "bwd")
+                assert host[0].start >= last_bwd
+                assert host[1].start >= host[0].end
+                # bytes that moved: the fp32 host vector, down and back
+                assert [sp.nbytes for sp in host] == [4.0 * counts[s]] * 2
+
+
+def test_tracing_leaves_the_numerics_bit_identical(numeric_local_runs):
+    import jax
+    import numpy as np
+
+    _, _, _, traced, plain = numeric_local_runs
+    assert traced.losses == plain.losses
+    for a, b in zip(jax.tree.leaves(traced.params),
+                    jax.tree.leaves(plain.params)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_host_work_rows_in_health_and_attribution(numeric_local_runs):
+    d, plan, ex, res, _ = numeric_local_runs
+    tr = res.trace
+    h = pipeline_health(tr)
+    n = 2 * d * 2                               # workers x steps
+    assert {op: row["count"] for op, row in h["host"].items()} == \
+        {"pack": n, "update": n}
+    assert h["host"]["pack"]["bytes"] == 4.0 * d * 2 * sum(
+        _stage_param_counts(plan, ex))
+    for row in h["stages"]:                     # host work is not compute
+        assert row["compute_frac"] + row["bubble_frac"] == pytest.approx(1.0)
+        assert row["bubble_frac"] > 0.0
+    # the perf model has no term for host work: observed rows, predicted 0
+    sim = plan.simulate(trace=True)
+    rows = gap_attribution(tr, predicted=sim.trace.spans)
+    host = [r for r in rows if r.op in ("pack", "update")]
+    assert {(r.stage, r.op) for r in host} == \
+        {(s, op) for s in range(2) for op in ("pack", "update")}
+    assert all(r.phase == "sync" and r.predicted_s == 0.0
+               and r.observed_s > 0.0 for r in host)
+
+
+def test_emulated_numeric_trace_has_no_host_spans():
+    from repro.api import ExecutionConfig, numeric_plan
+
+    plan, prof, ex = numeric_plan("phi3-mini-3.8b@reduced", stages=2, dp=1,
+                                  batch=8, seq=16)
+    res = plan.emulate(ExecutionConfig(steps=1, trace=True), execution=ex,
+                       profile=prof)
+    ops = {sp.op for sp in res.trace.spans}
+    assert "compute" in ops and not ops & {"pack", "update"}
+
+
+def test_program_spans_are_profiler_host_events_on_one_clock(tmp_path):
+    """Every span of a traced local run is also a host event of a
+    jax.profiler session, named ``s{stage}r{replica} {phase}.{op}`` with
+    its step as an argument; starts differ by one offset and durations
+    agree."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, _, res = _numeric_local(1, trace=True)
+    finally:
+        jax.profiler.stop_trace()
+    spans = {}
+    for sp in res.trace.spans:
+        spans.setdefault((f"{sp.worker} {sp.phase}.{sp.op}", sp.step),
+                         []).append(sp)
+    labels = {label for label, _ in spans}
+    path = sorted(glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                         "*", "*.xplane.pb")))[-1]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in labels:
+                    step = dict(e.stats).get("step")
+                    events.setdefault((e.name, step), []).append(
+                        (e.start_ns * 1e-9, e.duration_ns * 1e-9))
+    offsets, n = [], 0
+    for key, mine in spans.items():
+        evs = sorted(events.get(key, []))
+        assert len(evs) == len(mine), key
+        for sp, (start, dur) in zip(sorted(mine, key=lambda s: s.start), evs):
+            offsets.append(start - sp.start)
+            assert abs(dur - sp.duration) < 2e-3, (key, dur, sp.duration)
+            n += 1
+    assert n == len(res.trace.spans)
+    assert {k[0].split(" ")[1] for k in spans} >= {"sync.pack", "sync.update"}
+    assert max(offsets) - min(offsets) < 2e-3
