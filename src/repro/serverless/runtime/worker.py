@@ -28,7 +28,9 @@ comparison.  Holding residuals between fwd and bwd is exactly what the
 paper's activation-memory term ``mu * a_i`` accounts for.  Gradients are
 accumulated in fp32 across micro-batches; ``grad_vector`` flattens them for
 the storage scatter-reduce and ``apply_update`` applies the optimizer on
-fp32 masters (same math as ``testing.pipeline_equiv.reference_step``).
+fp32 masters (same math as ``testing.pipeline_equiv.reference_step``); with
+``jit=True`` that is one jitted program per stage that donates the
+optimizer state.
 
 MoE note: the router aux loss is seeded per micro-batch (weight ``1/mu``),
 which matches full-batch routing only when the aux statistic is linear in
@@ -99,6 +101,11 @@ def stage_instance_ranges(cfg: ArchConfig, x) -> List[StageSpan]:
     return spans
 
 
+def _is_leaf_state(v) -> bool:
+    """One param's optimizer state: ``{"master": ..., <moments>}``."""
+    return isinstance(v, dict) and "master" in v
+
+
 class StageWorker:
     """One serverless function: params + optimizer shard for a stage span."""
 
@@ -138,15 +145,20 @@ class StageWorker:
         self.params = p
 
         # fp32 masters + optimizer state, per leaf (ZeRO-less: the stage owns
-        # its whole shard, replicas hold identical copies)
-        self.opt_state = jax.tree.map(
-            lambda a: {"master": a.astype(jnp.float32),
-                       **optimizer.init_state(a.astype(jnp.float32))},
-            self.params)
+        # its whole shard, replicas hold identical copies).  The update
+        # donates this state, so every master is a copy the worker owns:
+        # ``astype`` to the dtype a param already has returns the caller's
+        # array, and the params may be the caller's own init arrays.
+        def leaf_state(a):
+            master = jnp.array(a, jnp.float32, copy=True)
+            return {"master": master, **optimizer.init_state(master)}
+
+        self.opt_state = jax.tree.map(leaf_state, self.params)
 
         flat, self._treedef = jax.tree.flatten(self.params)
         self._shapes = [l.shape for l in flat]
         self._sizes = [int(np.prod(l.shape)) for l in flat]
+        self._dtypes = [l.dtype for l in flat]
         self.grad_nbytes = float(sum(self._sizes)) * 4  # fp32 sync payload
 
         self._vjps: Dict[int, Any] = {}
@@ -156,6 +168,9 @@ class StageWorker:
         self._saved_inputs: Dict[int, Tuple[Any, Any]] = {}
         self._saved_sigs: Dict[int, Any] = {}
         self._jitted: Dict[Any, Tuple[Any, Any]] = {}  # shape sig -> (fwd, bwd)
+        # one compiled optimizer program per stage, donating the state
+        self._update = (jax.jit(self._optimizer_update, donate_argnums=0)
+                        if jit else self._optimizer_update)
 
     # ------------------------------------------------------------- stage math
     def _stage_fn(self, params, x, batch_mb):
@@ -325,7 +340,9 @@ class StageWorker:
                 f"checkpointed stage state does not match stage {self.span.index}: "
                 f"{jax.tree.structure(state['params'])} != {treedef}")
         self.params = jax.tree.map(jnp.asarray, state["params"])
-        self.opt_state = jax.tree.map(jnp.asarray, state["opt_state"])
+        # copies: the update donates them, and ``state`` stays the caller's
+        self.opt_state = jax.tree.map(lambda a: jnp.array(a, copy=True),
+                                      state["opt_state"])
         self._vjps.clear()
         self._saved_inputs.clear()
         self._saved_sigs.clear()
@@ -338,31 +355,34 @@ class StageWorker:
         flat = jax.tree.leaves(self._grad_acc)
         return np.concatenate([np.asarray(l, np.float32).ravel() for l in flat])
 
+    def _optimizer_update(self, opt_state, flat_g, step):
+        """The optimizer on every leaf of the stage: ``flat_g`` (the flat
+        fp32 gradient) cut into the leaves' shapes by static slices, each
+        fp32 master stepped and cast to its param's dtype.  Returns the new
+        ``(params, opt_state)``.  :meth:`apply_update` runs it as one
+        compiled program, or op by op with ``jit=False``."""
+        with jax.named_scope("update"):
+            states = jax.tree.leaves(opt_state, is_leaf=_is_leaf_state)
+            params, new_states, off = [], [], 0
+            for st, shape, size, dtype in zip(states, self._shapes,
+                                              self._sizes, self._dtypes):
+                g = flat_g[off:off + size].reshape(shape)
+                off += size
+                moments = {k: v for k, v in st.items() if k != "master"}
+                master, moments = self.optimizer.update(
+                    g, st["master"], moments, step)
+                params.append(master.astype(dtype))
+                new_states.append({"master": master, **moments})
+        return (jax.tree.unflatten(self._treedef, params),
+                jax.tree.unflatten(self._treedef, new_states))
+
     def apply_update(self, reduced: np.ndarray, step: int) -> None:
-        """Optimizer step from the (already averaged) flat gradient."""
-        parts = []
-        off = 0
-        for shape, size in zip(self._shapes, self._sizes):
-            parts.append(jnp.asarray(reduced[off:off + size]).reshape(shape))
-            off += size
-        assert off == len(reduced), (off, len(reduced))
-        g_tree = jax.tree.unflatten(self._treedef, parts)
-
-        step_idx = jnp.asarray(step, jnp.int32)
-
-        def upd(g, st):
-            sub = {k: v for k, v in st.items() if k != "master"}
-            new_m, new_sub = self.optimizer.update(g, st["master"], sub, step_idx)
-            return new_m, {"master": new_m, **new_sub}
-
-        is_leaf = lambda v: isinstance(v, dict) and "master" in v
-        flat_g = jax.tree.leaves(g_tree)
-        flat_st, st_def = jax.tree.flatten(self.opt_state, is_leaf=is_leaf)
-        outs = [upd(g, st) for g, st in zip(flat_g, flat_st)]
-        flat_p, p_def = jax.tree.flatten(self.params)
-        new_params = [m.astype(p.dtype) for (m, _), p in zip(outs, flat_p)]
-        self.params = jax.tree.unflatten(p_def, new_params)
-        self.opt_state = jax.tree.unflatten(st_def, [st for _, st in outs])
+        """Optimizer step from the (already averaged) flat gradient: one
+        host -> device copy, then one call of the stage's update program."""
+        assert len(reduced) == sum(self._sizes), (len(reduced), self._sizes)
+        self.params, self.opt_state = self._update(
+            self.opt_state, jnp.asarray(reduced, jnp.float32),
+            jnp.asarray(step, jnp.int32))
         self._grad_acc = None
 
 
