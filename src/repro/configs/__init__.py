@@ -22,6 +22,7 @@ _ARCH_MODULES = {
     "qwen2.5-14b": "qwen2_5_14b",
     "dbrx-132b": "dbrx_132b",
     "xlstm-125m": "xlstm_125m",
+    "xlstm-7-1-125m": "xlstm_7_1_125m",
     "internlm2-20b": "internlm2_20b",
     "qwen3-moe-235b-a22b": "qwen3_moe_235b_a22b",
     "internvl2-26b": "internvl2_26b",

@@ -69,10 +69,26 @@ class MambaCfg:
 
 @dataclass(frozen=True)
 class XLSTMCfg:
+    """xLSTM block settings."""
+
     # Projection factor of the mLSTM up-projection and sLSTM ffn.
     m_proj_factor: float = 2.0
     s_proj_factor: float = 4.0 / 3.0
     conv_kernel: int = 4
+    # "registry": xlstm-125m's block (dense q/k/v, gates read from the
+    # up-projection, RMSNorm of the cell outputs, an sLSTM that carries its
+    # own FFN; prefill and decode exist).  "paper": arXiv:2405.04517's block,
+    # which trains only: q/k/v block-diagonal ("headwise") in blocks of
+    # ``qkv_block`` with the mLSTM gates read from (q, k, v), a learnable
+    # skip, per-head group norms of both cells' outputs, a causal convolution
+    # + SiLU in front of the sLSTM's input and forget gates, block-diagonal
+    # sLSTM gate inputs, and no sLSTM input-gate bias
+    block: str = "registry"
+    qkv_block: int = 4
+    # recompute each mLSTM mixer's internals in the backward pass instead of
+    # holding them from the forward (about 130 MB per block and micro-batch
+    # of 2 x 512 tokens at the paper's widths)
+    remat_mlstm: bool = False
 
 
 @dataclass(frozen=True)
@@ -105,6 +121,11 @@ class ArchConfig:
     tensor: int = 1
     # dtype of params/activations on the target hardware
     param_dtype: str = "bfloat16"
+    # activation of the gated dense feed-forward: silu (SwiGLU) | gelu (GeGLU)
+    ff_act: str = "silu"
+    # the norm before each block's sublayers and at the end: rms (RMSNorm) |
+    # layer (LayerNorm without a bias)
+    norm: str = "rms"
 
     # ------------------------------------------------------------------ helpers
     @property
@@ -142,7 +163,15 @@ class ArchConfig:
         n_global = sum(1 for s in self.period if s.mixer == ATTN and s.window == GLOBAL_WINDOW)
         return n_global < n_attn or n_attn * 4 <= len(self.period)
 
+    @property
+    def decodes(self) -> bool:
+        """False for blocks that only train: prefill and one-token decode
+        exist for the registry's xLSTM block, not the paper's."""
+        return self.xlstm is None or self.xlstm.block == "registry"
+
     def supports_shape(self, shape_name: str) -> bool:
+        if not self.decodes and INPUT_SHAPES[shape_name].kind != "train":
+            return False
         if self.is_encoder and shape_name in ("decode_32k", "long_500k"):
             return False
         if shape_name == "long_500k" and not self.subquadratic:
@@ -165,11 +194,26 @@ class ArchConfig:
                 mc = self.mamba or MambaCfg()
                 di = mc.d_inner(d)
                 total += d * 2 * di + di * mc.d_conv + di * (2 * mc.d_state + 2) + di * d
-            elif spec.mixer in (SLSTM, MLSTM):
+            elif spec.mixer in (SLSTM, MLSTM) and self.decodes:
                 xc = self.xlstm or XLSTMCfg()
                 f = xc.m_proj_factor if spec.mixer == MLSTM else xc.s_proj_factor
                 di = int(d * f)
                 total += 2 * d * di + di * d + 4 * d * di  # up/gate/down + gates
+            elif spec.mixer == MLSTM:   # the paper's block, counted exactly
+                xc = self.xlstm
+                di = int(d * xc.m_proj_factor)
+                H = self.n_heads
+                total += (2 * d * di + di * d           # up, output gate, down
+                          + 3 * di * xc.qkv_block       # headwise q, k, v
+                          + 3 * di * 2 * H + 2 * H      # gates from (q, k, v)
+                          + (xc.conv_kernel + 1) * di   # convolution, bias
+                          + 2 * di)                     # group norm, skip
+            elif spec.mixer == SLSTM:
+                xc = self.xlstm
+                dh = d // self.n_heads
+                total += (4 * d * dh + 4 * d * dh       # gate inputs, recurrence
+                          + 3 * d + d                   # gate biases, group norm
+                          + (xc.conv_kernel + 1) * d)   # convolution, bias
             if spec.ff == DENSE_FF:
                 total += 3 * d * self.d_ff
             elif spec.ff == MOE_FF:
@@ -261,4 +305,7 @@ def validate(cfg: ArchConfig) -> None:
         assert cfg.mamba is not None
     if any(s.mixer in (SLSTM, MLSTM) for s in cfg.period):
         assert cfg.xlstm is not None
+    assert cfg.ff_act in ("silu", "gelu"), cfg.ff_act
+    assert cfg.norm in ("rms", "layer"), cfg.norm
+    assert cfg.xlstm is None or cfg.xlstm.block in ("registry", "paper")
     assert 16 % cfg.stages == 0 and cfg.stages * cfg.tensor in (cfg.stages * cfg.tensor,)

@@ -1,4 +1,9 @@
-"""xlstm-125m  [ssm]  — alternating sLSTM + mLSTM blocks  [arXiv:2405.04517]
+"""xlstm-125m  [ssm]  — the registry's 1:1 xLSTM variant  [after arXiv:2405.04517]
+
+Not the paper's model: 12 blocks alternating mLSTM and sLSTM, dense q/k/v
+projections, RMSNorm in place of the group norms, no sLSTM convolution and
+an ungated GeLU feed-forward inside the sLSTM mixer (168.3M parameters).
+The paper's xLSTM[7:1] 125M-class model is ``xlstm-7-1-125m``.
 
 d_ff=0: xLSTM blocks carry their own up-projections (mLSTM pre-up-projection
 x2, sLSTM post-up-projection 4/3), so there is no separate FFN.

@@ -25,7 +25,7 @@ from repro.configs.base import ArchConfig, ATTN, MAMBA, MLSTM, SLSTM, GLOBAL_WIN
 from repro.core import collectives as cc
 from repro.core.plan import PipelinePlan
 from repro.models import attention, mamba, xlstm
-from repro.models.common import ParallelCtx, rms_norm
+from repro.models.common import ParallelCtx, block_norm
 from repro.models.transformer import period_decode, period_forward, period_prefill
 
 CE_CHUNK = 512
@@ -196,7 +196,7 @@ def pipeline_train_loss(
 
         def ce_fn():
             lab = _get_mb(batch_local, jnp.clip(out_idx, 0, mu - 1), mb)["labels"]
-            hn = rms_norm(x, params_local["final_norm"], cfg.norm_eps)
+            hn = block_norm(x, params_local["final_norm"], cfg)
             return _chunked_ce(hn, head_w, lab, shift, tp=tp,
                                tp_index=lax.axis_index("model") % tp)
 
@@ -289,7 +289,7 @@ def pipeline_decode_step(
         )
 
         def logit_fn():
-            hn = rms_norm(x, params_local["final_norm"], cfg.norm_eps)
+            hn = block_norm(x, params_local["final_norm"], cfg)
             return (hn @ head_w.T).astype(jnp.float32)
 
         out_idx = jnp.clip(t - (S_eff - 1), 0, mu - 1)
@@ -383,7 +383,7 @@ def pipeline_prefill(
         )
 
         def logit_fn():
-            hn = rms_norm(x[:, -1:], params_local["final_norm"], cfg.norm_eps)
+            hn = block_norm(x[:, -1:], params_local["final_norm"], cfg)
             return (hn @ head_w.T).astype(jnp.float32)
 
         out_idx = jnp.clip(t - (S_eff - 1), 0, mu - 1)

@@ -118,13 +118,20 @@ def mamba_pspecs(cfg: ArchConfig) -> dict:
     }
 
 
-def xlstm_pspecs(cfg: ArchConfig, kind: str) -> dict:
+def xlstm_pspecs(cfg: ArchConfig, spec: LayerSpec) -> dict:
     # Recurrent matrices couple the full width: run TP-replicated (DESIGN.md).
-    if kind == MLSTM:
+    paper = cfg.xlstm.block == "paper"
+    if spec.mixer == MLSTM:
         keys = ["w_up", "w_z", "conv_w", "conv_b", "wq", "wk", "wv",
                 "w_if", "b_i", "b_f", "out_norm", "w_down"]
+        if paper:
+            keys.append("skip")
     else:
-        keys = ["w_gates", "r_gates", "b_gates", "out_norm", "w_up_ff", "w_down_ff"]
+        keys = ["w_gates", "r_gates", "b_gates", "out_norm"]
+        if paper:
+            keys += ["conv_w", "conv_b"]
+        if spec.ff == NO_FF:      # the mixer carries its own FFN
+            keys += ["w_up_ff", "w_down_ff"]
     return {k: REPL for k in keys}
 
 
@@ -135,7 +142,7 @@ def layer_pspecs(cfg: ArchConfig, spec: LayerSpec) -> dict:
     elif spec.mixer == MAMBA:
         p["mixer"] = mamba_pspecs(cfg)
     else:
-        p["mixer"] = xlstm_pspecs(cfg, spec.mixer)
+        p["mixer"] = xlstm_pspecs(cfg, spec)
     if spec.ff != NO_FF:
         p["norm2"] = REPL
         p["ff"] = mlp_pspecs(cfg) if spec.ff == DENSE_FF else moe_pspecs(cfg)

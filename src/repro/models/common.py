@@ -50,6 +50,26 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * (1.0 + scale.astype(jnp.float32))).astype(dtype)
 
 
+def layer_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5,
+               groups: int = 1) -> jax.Array:
+    """LayerNorm without a bias of each of ``groups`` equal slices of the
+    last axis (``groups`` > 1: a group norm, as the xLSTM cells use per
+    head), scaled by (1 + scale) like :func:`rms_norm`."""
+    dtype = x.dtype
+    xs = x.astype(jnp.float32).reshape(*x.shape[:-1], groups, -1)
+    xs = xs - jnp.mean(xs, axis=-1, keepdims=True)
+    y = xs * jax.lax.rsqrt(jnp.mean(jnp.square(xs), axis=-1, keepdims=True) + eps)
+    return (y.reshape(x.shape) * (1.0 + scale.astype(jnp.float32))).astype(dtype)
+
+
+def block_norm(x: jax.Array, scale: jax.Array, cfg) -> jax.Array:
+    """The norm ``cfg.norm`` names, before a block's sublayers and at the
+    end of the model."""
+    if cfg.norm == "layer":
+        return layer_norm(x, scale, cfg.norm_eps)
+    return rms_norm(x, scale, cfg.norm_eps)
+
+
 def init_norm(d: int, dtype) -> jax.Array:
     return jnp.zeros((d,), dtype=dtype)
 

@@ -1,4 +1,5 @@
-"""SwiGLU feed-forward (dense).  Column-parallel gate/up, row-parallel down."""
+"""Gated feed-forward (dense): SwiGLU, or GeGLU (``ArchConfig.ff_act``).
+Column-parallel gate/up, row-parallel down."""
 from __future__ import annotations
 
 import jax
@@ -24,11 +25,17 @@ def mlp_forward(
     *,
     ctx: ParallelCtx = LOCAL_CTX,
     use_pallas: bool = False,
+    act: str = "silu",
 ) -> jax.Array:
     if use_pallas:
+        if act != "silu":
+            raise ValueError(f"the Pallas feed-forward is SwiGLU only, not "
+                             f"{act!r}")
         from repro.kernels import ops as kops
 
         h = kops.swiglu(x, p["w_gate"], p["w_up"])
+    elif act == "gelu":   # the exact (erf) GeLU, as PyTorch's default
+        h = jax.nn.gelu(x @ p["w_gate"], approximate=False) * (x @ p["w_up"])
     else:
         h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])
     return ctx.psum_tp(h @ p["w_down"])

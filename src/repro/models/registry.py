@@ -29,8 +29,8 @@ from repro.models.common import (
     ParallelCtx,
     LOCAL_CTX,
     dense_init,
+    block_norm,
     init_norm,
-    rms_norm,
     softmax_cross_entropy,
     vocab_parallel_cross_entropy,
 )
@@ -126,7 +126,7 @@ def forward(
         return x, aux
 
     h, auxs = jax.lax.scan(body, h, (params["layers"], mask))
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    h = block_norm(h, params["final_norm"], cfg)
     return h, jnp.sum(auxs)
 
 
@@ -202,7 +202,7 @@ def decode_step(
         return x, new_cache
 
     h, new_caches = jax.lax.scan(body, h, (params["layers"], caches, mask))
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    h = block_norm(h, params["final_norm"], cfg)
     return _logits(cfg, params, h), new_caches
 
 
@@ -229,5 +229,5 @@ def prefill(
         return x, caches
 
     h, caches = jax.lax.scan(body, h, (params["layers"], mask))
-    h_last = rms_norm(h[:, -1:], params["final_norm"], cfg.norm_eps)
+    h_last = block_norm(h[:, -1:], params["final_norm"], cfg)
     return _logits(cfg, params, h_last), caches

@@ -26,7 +26,7 @@ from repro.configs.base import (
     GLOBAL_WINDOW,
 )
 from repro.models import attention, mamba, mlp, moe, xlstm
-from repro.models.common import ParallelCtx, LOCAL_CTX, init_norm, rms_norm
+from repro.models.common import ParallelCtx, LOCAL_CTX, block_norm, init_norm
 import dataclasses as _dc
 
 
@@ -51,7 +51,8 @@ def init_layer_params(key, cfg: ArchConfig, spec: LayerSpec, dtype,
     elif spec.mixer == MLSTM:
         p["mixer"] = xlstm.init_mlstm_params(k1, cfg, dtype)
     elif spec.mixer == SLSTM:
-        p["mixer"] = xlstm.init_slstm_params(k1, cfg, dtype)
+        p["mixer"] = xlstm.init_slstm_params(k1, cfg, dtype,
+                                             inner_ff=spec.ff == NO_FF)
     if spec.ff != NO_FF:
         p["norm2"] = init_norm(cfg.d_model, dtype)
         if spec.ff == DENSE_FF:
@@ -75,7 +76,7 @@ def layer_forward(
 ) -> Tuple[jax.Array, jax.Array]:
     """One layer.  ``active`` (bool scalar) masks padding layers to identity.
     Returns (x, aux_loss)."""
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    h = block_norm(x, p["norm1"], cfg)
     if spec.mixer == ATTN:
         mix = attention.attn_forward(
             p["mixer"], h, cfg=cfg, spec=spec, positions=positions, ctx=ctx,
@@ -93,9 +94,10 @@ def layer_forward(
     x = x + gate * mix
     aux = jnp.zeros((), jnp.float32)
     if spec.ff != NO_FF:
-        h = rms_norm(x, p["norm2"], cfg.norm_eps)
+        h = block_norm(x, p["norm2"], cfg)
         if spec.ff == DENSE_FF:
-            ff = mlp.mlp_forward(p["ff"], h, ctx=ctx, use_pallas=use_pallas)
+            ff = mlp.mlp_forward(p["ff"], h, ctx=ctx, use_pallas=use_pallas,
+                                 act=cfg.ff_act)
         else:
             ff, aux = moe.moe_forward(p["ff"], h, cfg=cfg, ctx=ctx)
             aux = aux * jnp.asarray(active, jnp.float32)
@@ -126,7 +128,7 @@ def period_forward(
 # ---------------------------------------------------------------------- decode
 def layer_decode(p, x, cache, active, *, cfg, spec, ctx=LOCAL_CTX,
                  use_pallas=False):
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    h = block_norm(x, p["norm1"], cfg)
     if spec.mixer == ATTN:
         mix, new_cache = attention.attn_decode(
             p["mixer"], h, cache, cfg=cfg, spec=spec, ctx=ctx,
@@ -146,9 +148,9 @@ def layer_decode(p, x, cache, active, *, cfg, spec, ctx=LOCAL_CTX,
         lambda new, old: jnp.where(active, new, old), new_cache, cache
     )
     if spec.ff != NO_FF:
-        h = rms_norm(x, p["norm2"], cfg.norm_eps)
+        h = block_norm(x, p["norm2"], cfg)
         if spec.ff == DENSE_FF:
-            ff = mlp.mlp_forward(p["ff"], h, ctx=ctx)
+            ff = mlp.mlp_forward(p["ff"], h, ctx=ctx, act=cfg.ff_act)
         else:
             ff, _ = moe.moe_forward(p["ff"], h, cfg=cfg, ctx=ctx)
         x = x + gate * ff
@@ -169,7 +171,7 @@ def period_decode(period_params, x, caches, active, *, cfg, ctx=LOCAL_CTX,
 
 def layer_prefill(p, x, active, *, cfg, spec, positions, ctx=LOCAL_CTX, capacity=None):
     """Forward + cache construction (serving prefill)."""
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    h = block_norm(x, p["norm1"], cfg)
     if spec.mixer == ATTN:
         mix, cache = attention.attn_prefill(
             p["mixer"], h, cfg=cfg, spec=spec, positions=positions, ctx=ctx,
@@ -186,9 +188,9 @@ def layer_prefill(p, x, active, *, cfg, spec, positions, ctx=LOCAL_CTX, capacity
     gate = jnp.asarray(active, x.dtype)
     x = x + gate * mix
     if spec.ff != NO_FF:
-        h = rms_norm(x, p["norm2"], cfg.norm_eps)
+        h = block_norm(x, p["norm2"], cfg)
         if spec.ff == DENSE_FF:
-            ff = mlp.mlp_forward(p["ff"], h, ctx=ctx)
+            ff = mlp.mlp_forward(p["ff"], h, ctx=ctx, act=cfg.ff_act)
         else:
             ff, _ = moe.moe_forward(p["ff"], h, cfg=cfg, ctx=ctx)
         x = x + gate * ff

@@ -4,9 +4,20 @@ memory, sequential recurrence) following arXiv:2405.04517.
 mLSTM train/prefill uses the stabilized quadratic parallel form (attention-like
 [S,S] weights built from cumulative log-forget-gates); decode is the O(1)
 recurrence over the (C, n, m) state.  sLSTM is inherently sequential (its
-gates see h_{t-1}) and runs as a lax.scan over time; it carries its own
-post-up-projection FFN per the paper's block design, hence ff=NO_FF in the
-arch config.
+gates see h_{t-1}) and runs as a lax.scan over time.
+
+Two blocks share this code (``configs.base.XLSTMCfg``).  The registry's
+``xlstm-125m`` block: dense q/k/v, gates read from the up-projection, RMSNorm
+of the cell outputs, and an sLSTM that carries its own post-up-projection
+FFN (ff=NO_FF in the arch config).  The paper's block (``xlstm-7-1-125m``):
+block-diagonal ("headwise") q/k/v with the gates read from (q, k, v), a
+learnable skip, per-head group norms, a convolution in front of the sLSTM's
+input and forget gates with block-diagonal gate inputs and no input-gate
+bias, and the sLSTM's gated feed-forward as the layer's own ``ff``
+sublayer; it trains only.
+
+The stage programs carry the named scopes ``mlstm``, ``mlstm.memory``,
+``slstm`` and ``slstm.recurrence`` in their op metadata.
 
 TP note: mLSTM tensors are sliced on d_inner/heads; the sLSTM recurrent matrix
 R couples all of h, so sLSTM runs TP-replicated (documented in DESIGN.md).
@@ -19,7 +30,29 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.common import ParallelCtx, LOCAL_CTX, dense_init, rms_norm
+from repro.models.common import (ParallelCtx, LOCAL_CTX, dense_init,
+                                 layer_norm, rms_norm)
+
+
+def headwise(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Block-diagonal projection: x [..., n*b] through the n blocks
+    w [n, b, b'] -> [..., n*b']."""
+    n, b, _ = w.shape
+    y = jnp.einsum("...ni,nio->...no", x.reshape(*x.shape[:-1], n, b), w)
+    return y.reshape(*x.shape[:-1], -1)
+
+
+def _paper(cfg: ArchConfig) -> bool:
+    return cfg.xlstm.block == "paper"
+
+
+def _out_norm(h, w, heads: int, cfg: ArchConfig, dtype):
+    """Norm of a cell's output h [..., heads*dh] (float32), cast to
+    ``dtype``: the paper's per-head group norm, or the registry block's
+    RMSNorm over the whole width."""
+    if _paper(cfg):
+        return layer_norm(h, w, cfg.norm_eps, groups=heads).astype(dtype)
+    return rms_norm(h.astype(dtype), w, cfg.norm_eps)
 
 
 def _m_dims(cfg: ArchConfig, local_heads: int | None = None):
@@ -35,20 +68,25 @@ def init_mlstm_params(key, cfg: ArchConfig, dtype) -> dict:
     di, H = _m_dims(cfg)
     xc = cfg.xlstm
     ks = jax.random.split(key, 7)
-    return {
+    b = xc.qkv_block
+    qkv, gate_in = ((di // b, b, b), 3 * di) if _paper(cfg) else ((di, di), di)
+    p = {
         "w_up": dense_init(ks[0], (d, di), dtype),
         "w_z": dense_init(ks[1], (d, di), dtype),
         "conv_w": dense_init(ks[2], (xc.conv_kernel, di), dtype, scale=0.1),
         "conv_b": jnp.zeros((di,), dtype),
-        "wq": dense_init(ks[3], (di, di), dtype),
-        "wk": dense_init(ks[4], (di, di), dtype),
-        "wv": dense_init(ks[5], (di, di), dtype),
-        "w_if": dense_init(ks[6], (di, 2 * H), dtype),
+        "wq": dense_init(ks[3], qkv, dtype),
+        "wk": dense_init(ks[4], qkv, dtype),
+        "wv": dense_init(ks[5], qkv, dtype),
+        "w_if": dense_init(ks[6], (gate_in, 2 * H), dtype),
         "b_i": jnp.zeros((H,), dtype),
         "b_f": jnp.full((H,), 3.0, dtype),  # forget-gate bias toward remembering
         "out_norm": jnp.zeros((di,), dtype),
         "w_down": dense_init(ks[0], (di, d), dtype, scale=0.02 / max(1, cfg.n_layers) ** 0.5),
     }
+    if _paper(cfg):
+        p["skip"] = jnp.ones((di,), dtype)
+    return p
 
 
 def _conv1d(xc: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
@@ -69,13 +107,20 @@ def _mlstm_qkvgates(p, x, cfg):
     def heads(t):
         return t.reshape(B, S, H, dh)
 
-    q = heads(uc @ p["wq"])
-    k = heads(uc @ p["wk"]) / dh**0.5
-    v = heads(u @ p["wv"])
-    gates = (u @ p["w_if"]).astype(jnp.float32)  # [B,S,2H]
+    if _paper(cfg):   # the paper's cell: its gates read (q, k, v)
+        q, k, v = (headwise(uc, p["wq"]), headwise(uc, p["wk"]),
+                   headwise(u, p["wv"]))
+        gates = jnp.concatenate([q, k, v], axis=-1) @ p["w_if"]
+    else:
+        q, k, v = uc @ p["wq"], uc @ p["wk"], u @ p["wv"]
+        gates = u @ p["w_if"]
+    q = heads(q)
+    k = heads(k) / dh**0.5
+    v = heads(v)
+    gates = gates.astype(jnp.float32)  # [B,S,2H]
     log_i = gates[..., :H] + p["b_i"].astype(jnp.float32)
     log_f = jax.nn.log_sigmoid(gates[..., H:] + p["b_f"].astype(jnp.float32))
-    return q, k, v, z, log_i, log_f, H, dh
+    return q, k, v, z, log_i, log_f, H, dh, uc
 
 
 MLSTM_CHUNK = 256
@@ -88,8 +133,16 @@ def mlstm_forward(
     """Chunkwise-parallel stabilized mLSTM (TFLA-style): quadratic form inside
     fixed-size chunks + a sequential (C, n, m) state across chunks, so memory
     is O(S * chunk) instead of O(S^2).  x [B,S,d] -> [B,S,d]."""
+    def fwd(p, x):
+        return _mlstm_forward(p, x, cfg, ctx, return_state)
+
+    with jax.named_scope("mlstm"):
+        return (jax.checkpoint(fwd) if cfg.xlstm.remat_mlstm else fwd)(p, x)
+
+
+def _mlstm_forward(p, x, cfg, ctx, return_state):
     B, S, _ = x.shape
-    q, k, v, z, log_i, log_f, H, dh = _mlstm_qkvgates(p, x, cfg)
+    q, k, v, z, log_i, log_f, H, dh, uc = _mlstm_qkvgates(p, x, cfg)
     Q = min(MLSTM_CHUNK, S)
     assert S % Q == 0, f"seq {S} not a multiple of mLSTM chunk {Q}"
     nchunks = S // Q
@@ -142,11 +195,15 @@ def mlstm_forward(
         jnp.zeros((B, H, dh), jnp.float32),
         jnp.full((B, H), -1e30, jnp.float32),
     )
-    (C_f, n_f, m_f), hs = jax.lax.scan(
-        jax.checkpoint(chunk_body), state0, (qf, kf, vf, lif, lff)
-    )
-    h = hs.swapaxes(0, 1).reshape(B, S, -1).astype(x.dtype)
-    h = rms_norm(h, p["out_norm"], cfg.norm_eps) * jax.nn.silu(z)
+    with jax.named_scope("mlstm.memory"):
+        (C_f, n_f, m_f), hs = jax.lax.scan(
+            jax.checkpoint(chunk_body), state0, (qf, kf, vf, lif, lff)
+        )
+    h = _out_norm(hs.swapaxes(0, 1).reshape(B, S, -1), p["out_norm"], H, cfg,
+                  x.dtype)
+    if _paper(cfg):
+        h = h + p["skip"] * uc
+    h = h * jax.nn.silu(z)
     out = ctx.psum_tp(h @ p["w_down"])
     if return_state:
         kc = cfg.xlstm.conv_kernel - 1
@@ -215,17 +272,29 @@ def _s_dims(cfg: ArchConfig):
     return d, H, dh, f_ff
 
 
-def init_slstm_params(key, cfg: ArchConfig, dtype) -> dict:
+def init_slstm_params(key, cfg: ArchConfig, dtype, inner_ff: bool = True) -> dict:
+    """``inner_ff``: the mixer carries its own post-up-projection FFN (the
+    registry's block); the paper's block has it as the layer's ``ff``."""
     d, H, dh, f_ff = _s_dims(cfg)
+    xc = cfg.xlstm
     ks = jax.random.split(key, 4)
-    return {
-        "w_gates": dense_init(ks[0], (d, 4 * d), dtype),
+    p = {
         "r_gates": dense_init(ks[1], (H, dh, 4 * dh), dtype, scale=dh**-0.5),
-        "b_gates": jnp.zeros((4 * d,), dtype),
         "out_norm": jnp.zeros((d,), dtype),
-        "w_up_ff": dense_init(ks[2], (d, f_ff), dtype),
-        "w_down_ff": dense_init(ks[3], (f_ff, d), dtype, scale=0.02 / max(1, cfg.n_layers) ** 0.5),
     }
+    if _paper(cfg):   # gates (z, i, f, o), each block-diagonal per head
+        p["w_gates"] = dense_init(ks[0], (4, H, dh, dh), dtype)
+        p["b_gates"] = jnp.zeros((3 * d,), dtype)   # z, f, o (see below)
+        p["conv_w"] = dense_init(jax.random.fold_in(key, 4),
+                                 (xc.conv_kernel, d), dtype, scale=0.1)
+        p["conv_b"] = jnp.zeros((d,), dtype)
+    else:
+        p["w_gates"] = dense_init(ks[0], (d, 4 * d), dtype)
+        p["b_gates"] = jnp.zeros((4 * d,), dtype)
+    if inner_ff:
+        p["w_up_ff"] = dense_init(ks[2], (d, f_ff), dtype)
+        p["w_down_ff"] = dense_init(ks[3], (f_ff, d), dtype, scale=0.02 / max(1, cfg.n_layers) ** 0.5)
+    return p
 
 
 class SLSTMCache(NamedTuple):
@@ -263,22 +332,52 @@ def slstm_forward(
     p: dict, x: jax.Array, *, cfg: ArchConfig, ctx: ParallelCtx = LOCAL_CTX,
     return_state: bool = False,
 ):
-    """Sequential sLSTM over the sequence + post-up FFN.  x [B,S,d]."""
-    B, S, d = x.shape
-    xg = x @ p["w_gates"] + p["b_gates"]  # [B,S,4d]
-    state = init_slstm_cache(B, cfg, x.dtype)
+    """Sequential sLSTM over the sequence (+ the post-up FFN where the mixer
+    carries it).  x [B,S,d]."""
+    with jax.named_scope("slstm"):
+        B, S, d = x.shape
+        xg = _slstm_gate_inputs(p, x, cfg)  # [B,S,4d]
+        state = init_slstm_cache(B, cfg, x.dtype)
+        if _paper(cfg):
+            # R in float32 outside the scan, so that its gradient sums the
+            # steps in float32: cast inside the step, each step's share is
+            # added to a bfloat16 sum, which drops the small ones (7.6 % of
+            # its norm at 512 steps and the paper's widths, on a CPU)
+            p = dict(p, r_gates=p["r_gates"].astype(jnp.float32))
 
-    def step(st, xg_t):
-        st2, h = _slstm_cell(p, cfg, xg_t, st)
-        return st2, h
+        def step(st, xg_t):
+            st2, h = _slstm_cell(p, cfg, xg_t, st)
+            return st2, h
 
-    st_f, hs = jax.lax.scan(step, state, jnp.swapaxes(xg, 0, 1))  # [S,B,H,dh]
-    h = jnp.swapaxes(hs, 0, 1).reshape(B, S, d).astype(x.dtype)
-    h = rms_norm(h, p["out_norm"], cfg.norm_eps)
-    ff = jax.nn.gelu(h @ p["w_up_ff"]) @ p["w_down_ff"]
+        with jax.named_scope("slstm.recurrence"):
+            st_f, hs = jax.lax.scan(step, state, jnp.swapaxes(xg, 0, 1))  # [S,B,H,dh]
+        h = _out_norm(jnp.swapaxes(hs, 0, 1).reshape(B, S, d), p["out_norm"],
+                      cfg.n_heads, cfg, x.dtype)
+        if "w_up_ff" in p:
+            h = jax.nn.gelu(h @ p["w_up_ff"]) @ p["w_down_ff"]
     if return_state:
-        return ff, st_f
-    return ff
+        return h, st_f
+    return h
+
+
+def _slstm_gate_inputs(p, x, cfg: ArchConfig) -> jax.Array:
+    """Input contribution to the gates, [B,S,4d] laid out (head, gate, dh)
+    with gates (z, i, f, o).  In the paper's block the input and forget
+    gates read SiLU(causal conv(x)), z and o read x, each through its own
+    block-diagonal per-head weights; and the input gate has no bias: h =
+    o * c / n, with c and n sums over the same input-gate weights, is the
+    same for any constant added to the input gate at every step, so such a
+    bias would get no gradient and move under Adam by round-off alone."""
+    if not _paper(cfg):
+        return x @ p["w_gates"] + p["b_gates"]
+    B, S, d = x.shape
+    _, H, dh, _ = _s_dims(cfg)
+    xc = jax.nn.silu(_conv1d(x, p["conv_w"], p["conv_b"]))
+    src = jnp.stack([x, xc, xc, x]).reshape(4, B, S, H, dh)
+    g = jnp.einsum("gbshd,ghde->bshge", src, p["w_gates"])
+    bz, bf, bo = jnp.moveaxis(p["b_gates"].reshape(H, 3, dh), 1, 0)
+    g = g + jnp.stack([bz, jnp.zeros_like(bz), bf, bo], axis=1)
+    return g.reshape(B, S, 4 * d)
 
 
 def slstm_decode(

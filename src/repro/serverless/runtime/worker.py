@@ -5,7 +5,7 @@ planner assigned to one pipeline stage — a range of period instances plus,
 for the boundary stages, the embedding table / final norm + LM head — and
 executes the same math as the monolithic ``registry.loss_fn`` /
 ``core.pipeline.pipeline_train_loss`` paths: ``embed_inputs`` ->
-``period_forward`` scan -> ``rms_norm`` + CE.  Because the instance scan is
+``period_forward`` scan -> ``block_norm`` + CE.  Because the instance scan is
 simply split at stage boundaries, the engine's pipelined execution is
 numerically the monolithic forward, up to fp32 summation order.
 
@@ -49,7 +49,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.partition import stages_of
 from repro.models import registry
-from repro.models.common import rms_norm, softmax_cross_entropy
+from repro.models.common import block_norm, softmax_cross_entropy
 from repro.models.transformer import period_forward
 from repro.optim import Optimizer
 
@@ -99,6 +99,14 @@ def stage_instance_ranges(cfg: ArchConfig, x) -> List[StageSpan]:
             owns_embed=(lo == 0), owns_head=(hi == L - 1),
         ))
     return spans
+
+
+#: a TPU tile's minor dimension.  XLA rewrites a slice of the flat gradient
+#: followed by a reshape to a leaf whose last dim k is not a multiple of it
+#: into a reshape of the whole vector to [n/k, k], tiled and padded to 128
+#: lanes: 12.3 GB of temporaries for xlstm-7-1-125m's stage 0, whose q/k/v
+#: blocks end in 4.  An optimization barrier keeps such a slice first.
+_LANES = 128
 
 
 def _is_leaf_state(v) -> bool:
@@ -191,7 +199,7 @@ class StageWorker:
             x, auxs = jax.lax.scan(body, x, (params["layers"], self.mask))
             aux = aux + jnp.sum(auxs)
         if self.span.owns_head:
-            h = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            h = block_norm(x, params["final_norm"], cfg)
             head_w = params["embed"] if cfg.tie_embeddings else params["head"]
             logits = h @ head_w.T
             labels = batch_mb["labels"]
@@ -366,7 +374,10 @@ class StageWorker:
             params, new_states, off = [], [], 0
             for st, shape, size, dtype in zip(states, self._shapes,
                                               self._sizes, self._dtypes):
-                g = flat_g[off:off + size].reshape(shape)
+                g = flat_g[off:off + size]
+                if shape and shape[-1] % _LANES:
+                    g = jax.lax.optimization_barrier(g)
+                g = g.reshape(shape)
                 off += size
                 moments = {k: v for k, v in st.items() if k != "master"}
                 master, moments = self.optimizer.update(

@@ -33,16 +33,24 @@ def arch_config_for_model(model: str):
 
     Reads the spellings of ``repro.configs.resolve_arch`` but *rejects* the
     paper's Table 1 models: they are analytic layer tables with no runnable
-    layers, and serving needs executable prefill/decode math.
+    layers, and serving needs executable prefill/decode math.  Raises
+    ``NotImplementedError`` for an architecture that trains only
+    (``ArchConfig.decodes``).
     """
     from repro.configs import resolve_arch
 
     try:
-        return resolve_arch(model)
+        cfg = resolve_arch(model)
     except KeyError as e:
         raise KeyError(
             f"serving needs an executable architecture (paper Table 1 "
             f"models are analytic-only): {e.args[0]}") from None
+    if not cfg.decodes:
+        raise NotImplementedError(
+            f"serving needs prefill and decode, which {cfg.name}'s blocks "
+            f"(the paper's xLSTM: headwise q/k/v, group norms, sLSTM "
+            f"convolution) do not have; it trains only")
+    return cfg
 
 
 @dataclass(frozen=True)

@@ -18,7 +18,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import registry
-from repro.models.common import rms_norm
+from repro.models.common import block_norm
 from repro.models.transformer import period_decode, period_prefill
 from repro.serverless.runtime.worker import StageSpan
 
@@ -85,7 +85,7 @@ class ServeStageWorker:
         return params["embed"][x_in] if self.span.owns_embed else x_in
 
     def _head(self, params, h):
-        h = rms_norm(h, params["final_norm"], self.cfg.norm_eps)
+        h = block_norm(h, params["final_norm"], self.cfg)
         head = (params["embed"] if self.cfg.tie_embeddings
                 else params["head"])
         return h @ head.T

@@ -12,7 +12,8 @@ from _hypo import given, settings, st
 from repro.configs import get_config
 from repro.configs.base import InputShape, LayerSpec
 from repro.models import attention, mamba, moe, registry, xlstm
-from repro.models.common import softmax_cross_entropy, vocab_parallel_cross_entropy
+from repro.models.common import (layer_norm, softmax_cross_entropy,
+                                 vocab_parallel_cross_entropy)
 
 
 DECODABLE = ["phi3-mini-3.8b", "gemma3-4b", "qwen2.5-14b", "internlm2-20b",
@@ -183,3 +184,129 @@ def test_window_cache_ring_semantics():
         outs.append(y)
     got = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(full), rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------- the paper's xLSTM[7:1] (xlstm-7-1-125m)
+def test_headwise_projection_is_block_diagonal_dense():
+    n, b = 6, 4
+    w = jax.random.normal(jax.random.PRNGKey(0), (n, b, b))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, n * b))
+    dense = jax.scipy.linalg.block_diag(*w)          # [n*b, n*b]
+    np.testing.assert_allclose(np.asarray(xlstm.headwise(x, w)),
+                               np.asarray(x @ dense), rtol=1e-5, atol=1e-5)
+
+
+def test_paper_slstm_scan_matches_a_loop_over_the_cell():
+    from repro.configs import resolve_arch
+    from repro.configs.base import SLSTM
+
+    cfg = resolve_arch("xlstm-7-1-125m@reduced")
+    assert cfg.xlstm.block == "paper"
+    p = xlstm.init_slstm_params(jax.random.PRNGKey(0), cfg, jnp.float32,
+                                inner_ff=False)
+    assert "w_up_ff" not in p and p["w_gates"].ndim == 4
+    B, S = 2, 12
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (B, S, cfg.d_model))
+    got = xlstm.slstm_forward(p, x, cfg=cfg)
+    xg = xlstm._slstm_gate_inputs(p, x, cfg)
+    st = xlstm.init_slstm_cache(B, cfg, x.dtype)
+    hs = []
+    for t in range(S):
+        st, h = xlstm._slstm_cell(p, cfg, xg[:, t], st)
+        hs.append(h.reshape(B, cfg.d_model))
+    want = layer_norm(jnp.stack(hs, axis=1), p["out_norm"], cfg.norm_eps,
+                      groups=cfg.n_heads)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    # the convolution feeds only the input and forget gates: a change of x
+    # at t moves the z/o gate inputs of t alone, the i/f inputs of t..t+3
+    dx = x.at[:, 5].add(1.0)
+    moved = jnp.abs(xlstm._slstm_gate_inputs(p, dx, cfg) - xg).reshape(
+        B, S, cfg.n_heads, 4, -1).max(axis=(0, 2, 4))       # [S, gate]
+    assert np.all(np.asarray(moved[[5], :]) > 0)
+    assert np.all(np.asarray(moved[6:9, [1, 2]]) > 0)
+    assert np.all(np.asarray(moved[6:, [0, 3]]) == 0)
+    assert cfg.period[7].mixer == SLSTM
+
+
+def test_slstm_is_blind_to_an_input_gate_bias():
+    """Why the paper's sLSTM has no input-gate bias here: a constant added
+    to the input gate's pre-activation at every step leaves h as it was, so
+    such a bias would get no gradient."""
+    from repro.configs import resolve_arch
+
+    cfg = resolve_arch("xlstm-7-1-125m@reduced")
+    p = xlstm.init_slstm_params(jax.random.PRNGKey(0), cfg, jnp.float32,
+                                inner_ff=False)
+    assert p["b_gates"].shape == (3 * cfg.d_model,)
+    B, S = 2, 12
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (B, S, cfg.d_model))
+    xg = xlstm._slstm_gate_inputs(p, x, cfg)
+
+    def run(shift):
+        g = xg.reshape(B, S, cfg.n_heads, 4, -1).at[:, :, :, 1].add(shift)
+        st, hs = xlstm.init_slstm_cache(B, cfg, x.dtype), []
+        for t in range(S):
+            st, h = xlstm._slstm_cell(p, cfg, g[:, t].reshape(B, -1), st)
+            hs.append(h)
+        return jnp.stack(hs)
+
+    np.testing.assert_allclose(np.asarray(run(2.5)), np.asarray(run(0.0)),
+                               rtol=1e-5, atol=1e-6)
+    assert float(jnp.max(jnp.abs(run(0.0)))) > 0.01
+
+
+def test_xlstm_7_1_125m_is_the_papers_model():
+    from repro.configs import resolve_arch
+    from repro.configs.base import DENSE_FF, MLSTM, NO_FF, SLSTM
+
+    cfg = resolve_arch("xlstm-7-1-125m")
+    kinds = [cfg.layer_spec(i).mixer for i in range(cfg.n_layers)]
+    assert cfg.n_layers == 24 and cfg.n_periods == 3
+    assert kinds == ([MLSTM] * 7 + [SLSTM]) * 3
+    assert all(cfg.layer_spec(i).ff == (DENSE_FF if k == SLSTM else NO_FF)
+               for i, k in enumerate(kinds))
+    xc = cfg.xlstm
+    assert (cfg.d_model, cfg.n_heads, int(cfg.d_model * xc.m_proj_factor),
+            xc.qkv_block, xc.conv_kernel, cfg.d_ff, cfg.ff_act,
+            cfg.vocab_size, cfg.norm) == (768, 4, 1536, 4, 4, 1024, "gelu",
+                                          50304, "layer")
+    assert not cfg.tie_embeddings
+    shapes = jax.eval_shape(lambda k: registry.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    n = sum(a.size for a in jax.tree.leaves(shapes))
+    assert n == pytest.approx(163.7e6, rel=0.01)
+    assert cfg.param_count() == pytest.approx(n, rel=1e-3)
+    # the registry's 1:1 variant is unchanged and still decodes
+    old = resolve_arch("xlstm-125m")
+    assert old.decodes and not cfg.decodes
+    assert not cfg.supports_shape("decode_32k")
+    assert cfg.supports_shape("train_4k")
+
+
+def test_serving_refuses_the_papers_xlstm():
+    from repro.serverless.platform import get_platform
+    from repro.serving import arch_config_for_model, plan_serving
+
+    with pytest.raises(NotImplementedError, match="trains only"):
+        arch_config_for_model("xlstm-7-1-125m@reduced")
+    with pytest.raises(NotImplementedError, match="prefill and decode"):
+        plan_serving("xlstm-7-1-125m@reduced", get_platform("aws"), slo=60.0)
+
+
+def test_stage_program_carries_the_xlstm_scopes():
+    """A lowered stage forward of the reduced model names the mLSTM, its
+    memory, the sLSTM and its recurrence in its HLO op metadata."""
+    from repro.api import numeric_plan
+    from repro.serverless.runtime.worker import StageWorker, stage_instance_ranges
+
+    plan, _, ex = numeric_plan("xlstm-7-1-125m@reduced16", stages=2, dp=1,
+                               batch=4, seq=16)
+    span = stage_instance_ranges(ex.cfg, plan.x)[0]
+    w = StageWorker(ex.cfg, span, ex.init_params, mu=2, optimizer=ex.optimizer)
+    batch = ex.batch_fn(0)
+    mb = {k: v[:2] for k, v in batch.items()}
+    hlo = jax.jit(w._stage_fn).lower(w.params, None, mb).as_text(
+        debug_info=True)
+    for scope in ("mlstm/", "mlstm.memory/", "slstm/", "slstm.recurrence/"):
+        assert scope in hlo, scope

@@ -93,3 +93,26 @@ def test_kernel_compiles_for_v5e(compile_for_tpu, kernel, shapes):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2**30
+
+
+def test_stage_update_keeps_small_leaves_unpadded(compile_for_tpu, one_chip):
+    """The compiled optimizer update of a stage whose leaves end in a dim
+    under a tile's 128 lanes (xlstm-7-1-125m's q/k/v blocks [.., 4, 4] and
+    gate biases [.., 4]) holds a few flat vectors of temporaries, not the
+    whole flat gradient reshaped to [n/4, 4] and padded to 128 lanes (32x)."""
+    from repro.api import numeric_plan
+    from repro.serverless.runtime.worker import StageWorker, stage_instance_ranges
+
+    # one stage holding both periods: leaves stacked over two instances
+    plan, _, ex = numeric_plan("xlstm-7-1-125m@reduced16", stages=1, dp=1,
+                               batch=4, seq=16)
+    span = stage_instance_ranges(ex.cfg, plan.x)[0]
+    w = StageWorker(ex.cfg, span, ex.init_params, mu=2, optimizer=ex.optimizer)
+    n = sum(w._sizes)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                             sharding=one_chip)
+    compiled = jax.jit(w._optimizer_update, donate_argnums=0).lower(
+        jax.tree.map(on_chip, w.opt_state),
+        on_chip(jax.ShapeDtypeStruct((n,), jnp.float32)),
+        on_chip(jax.ShapeDtypeStruct((), jnp.int32))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 4 * n
