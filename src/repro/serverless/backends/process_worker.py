@@ -616,7 +616,8 @@ def worker_main(conn, init: dict) -> None:
         spans = stage_instance_ranges(es["cfg"], es["x"])
         worker = StageWorker(es["cfg"], spans[s], es["init_params"],
                              mu=es["mu"], optimizer=es["optimizer"],
-                             jit=es["jit"], remat=es["remat"])
+                             replicas=int(init["agg"].d), jit=es["jit"],
+                             remat=es["remat"])
         # cheap in-process reset snapshot: load_state keeps jit caches warm
         initial_state = _np_tree(worker.export_state())
 

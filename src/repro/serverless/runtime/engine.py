@@ -118,8 +118,9 @@ def _worker_step_program(ctx: WorkerContext, *, k: int, s: int, r: int, agg,
     view, shared by every backend: ``mu`` forward micro-batches (yield after
     each op group so virtual-clock drivers can interleave workers), the
     fwd/bwd phase fence, ``mu`` backwards in reverse order, then a
-    ``("sync", grad_vector)`` yield answered by the backend with the reduced
-    gradient, from which the worker applies its optimizer update.  Packing
+    ``("sync", grad_vector)`` yield answered by the backend with the
+    gradient summed over the stage's replicas, from which the worker's
+    optimizer update takes the mean and steps.  Packing
     the gradient and applying the update are the worker's host work
     (``ctx.host``: ``"pack"`` and ``"update"`` spans on traced wall-clock
     backends); timing-only plans (no ``worker``) have neither.
@@ -172,7 +173,7 @@ def _worker_step_program(ctx: WorkerContext, *, k: int, s: int, r: int, agg,
         return
     vec = ctx.host("pack", worker.grad_vector)
     reduced = yield ("sync", vec)
-    ctx.host("update", lambda: worker.apply_update(reduced / d, step=k),
+    ctx.host("update", lambda: worker.apply_update(reduced, step=k),
              nbytes=reduced.nbytes)
     losses[(s, r)] = (ce_acc, aux_acc)
 
@@ -313,7 +314,8 @@ def run_plan(
         assert len(spans) == S
         return [[StageWorker(execution.cfg, spans[s], execution.init_params,
                              mu=mu, optimizer=execution.optimizer,
-                             jit=execution.jit, remat=execution.remat)
+                             replicas=d, jit=execution.jit,
+                             remat=execution.remat)
                  for r in range(d)] for s in range(S)]
 
     be.open(agg)

@@ -26,11 +26,12 @@ residuals instead of recomputing the forward inside the VJP — the
 recompute variant is kept behind ``remat=True`` for the A/B wall-clock
 comparison.  Holding residuals between fwd and bwd is exactly what the
 paper's activation-memory term ``mu * a_i`` accounts for.  Gradients are
-accumulated in fp32 across micro-batches; ``grad_vector`` flattens them for
-the storage scatter-reduce and ``apply_update`` applies the optimizer on
-fp32 masters (same math as ``testing.pipeline_equiv.reference_step``); with
-``jit=True`` that is one jitted program per stage that donates the
-optimizer state.
+accumulated in fp32 across micro-batches; ``grad_vector`` flattens them on
+the device and hands the storage scatter-reduce one host vector, and
+``apply_update`` takes the replicas' sum back, takes the mean and applies the
+optimizer on fp32 masters (same math as
+``testing.pipeline_equiv.reference_step``); with ``jit=True`` each is one
+jitted program per stage, and the update donates the optimizer state.
 
 MoE note: the router aux loss is seeded per micro-batch (weight ``1/mu``),
 which matches full-batch routing only when the aux statistic is linear in
@@ -105,8 +106,14 @@ def stage_instance_ranges(cfg: ArchConfig, x) -> List[StageSpan]:
 #: followed by a reshape to a leaf whose last dim k is not a multiple of it
 #: into a reshape of the whole vector to [n/k, k], tiled and padded to 128
 #: lanes: 12.3 GB of temporaries for xlstm-7-1-125m's stage 0, whose q/k/v
-#: blocks end in 4.  An optimization barrier keeps such a slice first.
+#: blocks end in 4.  An optimization barrier keeps such a slice first, and
+#: keeps such a leaf's ravel apart from the pack's concatenation.
 _LANES = 128
+
+
+def _narrow(shape) -> bool:
+    """A leaf whose last dim does not fill a tile's lanes (see ``_LANES``)."""
+    return bool(shape) and shape[-1] % _LANES != 0
 
 
 def _is_leaf_state(v) -> bool:
@@ -118,8 +125,8 @@ class StageWorker:
     """One serverless function: params + optimizer shard for a stage span."""
 
     def __init__(self, cfg: ArchConfig, span: StageSpan, full_params: dict,
-                 *, mu: int, optimizer: Optimizer, jit: bool = True,
-                 remat: bool = False):
+                 *, mu: int, optimizer: Optimizer, replicas: int = 1,
+                 jit: bool = True, remat: bool = False):
         if cfg.frontend != "none":
             raise NotImplementedError(
                 "runtime numeric execution covers token-LM archs; "
@@ -130,6 +137,7 @@ class StageWorker:
         self.cfg = cfg
         self.span = span
         self.mu = mu
+        self.replicas = replicas
         self.optimizer = optimizer
         self.dtype = jnp.dtype(cfg.param_dtype)
 
@@ -176,7 +184,9 @@ class StageWorker:
         self._saved_inputs: Dict[int, Tuple[Any, Any]] = {}
         self._saved_sigs: Dict[int, Any] = {}
         self._jitted: Dict[Any, Tuple[Any, Any]] = {}  # shape sig -> (fwd, bwd)
-        # one compiled optimizer program per stage, donating the state
+        # one compiled pack and one compiled optimizer program per stage;
+        # the update donates the state
+        self._flatten = jax.jit(self._pack) if jit else self._pack
         self._update = (jax.jit(self._optimizer_update, donate_argnums=0)
                         if jit else self._optimizer_update)
 
@@ -357,15 +367,31 @@ class StageWorker:
         self._grad_acc = None
 
     # ------------------------------------------------------------------- sync
-    def grad_vector(self) -> np.ndarray:
-        """Accumulated stage gradient, flattened fp32 (scatter-reduce payload)."""
-        assert self._grad_acc is not None, "backward() must run first"
-        flat = jax.tree.leaves(self._grad_acc)
-        return np.concatenate([np.asarray(l, np.float32).ravel() for l in flat])
+    def _pack(self, grad_acc):
+        """Every fp32 accumulator leaf raveled and concatenated, in leaf
+        order: the flat gradient :meth:`grad_vector` hands the sync."""
+        with jax.named_scope("pack"):
+            flat = []
+            for g in jax.tree.leaves(grad_acc):
+                narrow = _narrow(g.shape)
+                g = g.ravel()
+                if narrow:
+                    g = jax.lax.optimization_barrier(g)
+                flat.append(g)
+            return jnp.concatenate(flat)
 
-    def _optimizer_update(self, opt_state, flat_g, step):
-        """The optimizer on every leaf of the stage: ``flat_g`` (the flat
-        fp32 gradient) cut into the leaves' shapes by static slices, each
+    def grad_vector(self) -> np.ndarray:
+        """Accumulated stage gradient, flattened fp32 (scatter-reduce
+        payload): one program on the device, then one copy to the host."""
+        assert self._grad_acc is not None, "backward() must run first"
+        flat = self._flatten(self._grad_acc)
+        self._grad_acc = None       # freed once the pack has read it
+        return np.asarray(flat)
+
+    def _optimizer_update(self, opt_state, flat_g, step, replicas):
+        """The optimizer on every leaf of the stage: ``flat_g`` (the fp32
+        sum of the flat gradient over the stage's ``replicas``) cut into the
+        leaves' shapes by static slices and divided by ``replicas``, each
         fp32 master stepped and cast to its param's dtype.  Returns the new
         ``(params, opt_state)``.  :meth:`apply_update` runs it as one
         compiled program, or op by op with ``jit=False``."""
@@ -375,9 +401,9 @@ class StageWorker:
             for st, shape, size, dtype in zip(states, self._shapes,
                                               self._sizes, self._dtypes):
                 g = flat_g[off:off + size]
-                if shape and shape[-1] % _LANES:
+                if _narrow(shape):
                     g = jax.lax.optimization_barrier(g)
-                g = g.reshape(shape)
+                g = g.reshape(shape) / replicas
                 off += size
                 moments = {k: v for k, v in st.items() if k != "master"}
                 master, moments = self.optimizer.update(
@@ -388,13 +414,16 @@ class StageWorker:
                 jax.tree.unflatten(self._treedef, new_states))
 
     def apply_update(self, reduced: np.ndarray, step: int) -> None:
-        """Optimizer step from the (already averaged) flat gradient: one
-        host -> device copy, then one call of the stage's update program."""
+        """Optimizer step from the flat gradient summed over the stage's
+        replicas: one host -> device copy, then one call of the stage's
+        update program, which takes the mean.  The replica count is a traced
+        fp32 scalar, so the divide stays a divide (IEEE-exact on the CPU,
+        the same bits as numpy's)."""
         assert len(reduced) == sum(self._sizes), (len(reduced), self._sizes)
         self.params, self.opt_state = self._update(
             self.opt_state, jnp.asarray(reduced, jnp.float32),
-            jnp.asarray(step, jnp.int32))
-        self._grad_acc = None
+            jnp.asarray(step, jnp.int32),
+            jnp.asarray(self.replicas, jnp.float32))
 
 
 def assemble_params(cfg: ArchConfig, workers: List[StageWorker]) -> dict:

@@ -1,11 +1,15 @@
-"""The stage worker's optimizer step: one compiled program per stage that
-donates the optimizer state.
+"""The stage worker's host round trip at the sync: one compiled pack per
+stage that flattens the gradient on the device, and one compiled optimizer
+step per stage that takes the replicas' mean and donates the optimizer
+state.
 
-The reference is the per-leaf eager AdamW formula, written out here: the
-compiled update must reproduce it on every master, moment and param, keep
-``export_state()["opt_state"]``'s per-leaf ``{"master", "m", "v"}`` layout,
-compile once per stage whatever the step count, and never donate a buffer
-the caller still holds (the plan's init params).
+The pack's reference is the per-leaf host ``np.concatenate``, which it must
+match bit for bit.  The update's is the per-leaf eager AdamW formula,
+written out here, from the host mean: the compiled update must reproduce it
+on every master, moment and param, keep ``export_state()["opt_state"]``'s
+per-leaf ``{"master", "m", "v"}`` layout, compile once per stage whatever the
+step count, and never donate a buffer the caller still holds (the plan's
+init params).
 """
 from __future__ import annotations
 
@@ -15,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.api import ExecutionConfig, numeric_plan
-from repro.serverless.runtime.worker import StageWorker
+from repro.serverless.runtime.worker import StageWorker, stage_instance_ranges
 
 MODEL = "phi3-mini-3.8b@reduced"    # float32 params: astype(f32) aliases
 STEPS = 3
@@ -59,9 +63,9 @@ def _host(tree):
 @pytest.mark.parametrize("d", [1, 2])
 def test_compiled_update_matches_eager_adamw(monkeypatch, d):
     """S=2 stages x d replicas, three steps through ``plan.emulate``: each
-    worker's compiled update gives the masters, moments and params that the
-    eager formula gives from the same start and the same averaged
-    gradients."""
+    worker's compiled update, handed the replicas' sum, gives the masters,
+    moments and params that the eager formula gives from the same start and
+    the sum's mean taken on the host."""
     plan, profile, ex = numeric_plan(MODEL, stages=2, dp=d, batch=8, seq=16)
     seen = {}                         # id -> (worker, start state, updates)
     apply_update = StageWorker.apply_update
@@ -69,7 +73,7 @@ def test_compiled_update_matches_eager_adamw(monkeypatch, d):
     def recording(self, reduced, step):
         if id(self) not in seen:
             seen[id(self)] = (self, _host(self.export_state()), [])
-        seen[id(self)][2].append((step, np.array(reduced, copy=True)))
+        seen[id(self)][2].append((step, np.array(reduced, copy=True) / d))
         apply_update(self, reduced, step)
 
     monkeypatch.setattr(StageWorker, "apply_update", recording)
@@ -106,18 +110,91 @@ def test_compiled_update_matches_eager_adamw(monkeypatch, d):
             assert p_have.dtype == p.dtype
 
 
-@pytest.mark.parametrize("backend", ["emulated", "local"])
-def test_update_compiles_once_per_stage(backend):
-    """Two consecutive calls on one ``Execution``, as the chip benchmark
-    makes them: each compiles every stage's update program once over its
-    steps, and the init params the calls share stay readable."""
+def _stage_worker(model, replicas=1, *, stages=2):
+    """Stage 0 of ``model`` planned as ``stages`` x 1, on the plan's init
+    params."""
+    plan, _, ex = numeric_plan(model, stages=stages, dp=1, batch=8, seq=16)
+    span = stage_instance_ranges(ex.cfg, plan.x)[0]
+    return StageWorker(ex.cfg, span, ex.init_params, mu=2,
+                       optimizer=ex.optimizer, replicas=replicas)
+
+
+def test_update_takes_the_replicas_mean_on_the_device():
+    """At d=2 the compiled update of the replicas' sum steps every master,
+    moment and param to the same bits as the update of the mean taken on
+    the host (the contract before the divide moved into the program)."""
+    on_device, on_host = _stage_worker(MODEL, 2), _stage_worker(MODEL, 1)
+    n = sum(on_device._sizes)
+    rng = np.random.default_rng(7)
+    for step in range(2):
+        parts = rng.standard_normal((2, n), dtype=np.float32)
+        total = parts[0] + parts[1]
+        on_device.apply_update(total, step)
+        on_host.apply_update(total / 2, step)
+    for have, want in zip(jax.tree.leaves(on_device.export_state()),
+                          jax.tree.leaves(on_host.export_state())):
+        np.testing.assert_array_equal(np.asarray(have), np.asarray(want))
+
+
+def _host_concatenate(grad_acc):
+    """The pack's reference: every leaf to the host, then concatenated."""
+    return np.concatenate([np.asarray(g, np.float32).ravel()
+                           for g in jax.tree.leaves(grad_acc)])
+
+
+@pytest.mark.parametrize("d", [1, 2])
+def test_pack_matches_host_concatenate(monkeypatch, d):
+    """S=2 stages x d replicas, three steps through ``plan.emulate``: every
+    worker's compiled pack returns, bit for bit, the per-leaf host
+    concatenation of its accumulated gradient, as one host array."""
+    plan, profile, ex = numeric_plan(MODEL, stages=2, dp=d, batch=8, seq=16)
+    grad_vector = StageWorker.grad_vector
+    packs = []
+
+    def checked(self):
+        want = _host_concatenate(self._grad_acc)
+        have = grad_vector(self)
+        assert isinstance(have, np.ndarray)
+        np.testing.assert_array_equal(have, want)
+        assert have.dtype == np.float32
+        packs.append(have.size)
+        return have
+
+    monkeypatch.setattr(StageWorker, "grad_vector", checked)
+    plan.emulate(ExecutionConfig(steps=STEPS), execution=ex, profile=profile)
+    assert len(packs) == STEPS * 2 * d
+
+
+def test_pack_of_narrow_leaves_matches_host_concatenate():
+    """xlstm-7-1-125m's stage leaves end in 4 (the q/k/v blocks and gate
+    biases) beside leaves of 128 lanes: the pack, which flattens those
+    behind an optimization barrier, still gives the host concatenation's
+    bits, and hands the next step a fresh accumulator."""
+    w = _stage_worker("xlstm-7-1-125m@reduced16", stages=1)
+    leaves = jax.tree.leaves(w.params)
+    assert any(a.shape[-1] == 4 for a in leaves)
+    assert any(a.shape[-1] % 128 == 0 for a in leaves)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+    w._grad_acc = jax.tree.unflatten(w._treedef, [
+        jax.random.normal(k, a.shape, jnp.float32)
+        for k, a in zip(keys, leaves)])
+    want = _host_concatenate(w._grad_acc)
+    np.testing.assert_array_equal(w.grad_vector(), want)
+    assert w._grad_acc is None
+
+
+def _compiles_per_call(backend, program):
+    """Backend compiles of the jitted ``program`` in each of two
+    consecutive calls on one ``Execution``, as the chip benchmark makes
+    them; also checks that the init params the calls share stay
+    readable."""
     plan, profile, ex = numeric_plan(MODEL, stages=2, dp=1, batch=8, seq=16)
     init = _host(ex.init_params)
-    compiles = []
+    compiles, counts = [], []
 
     def listen(event, duration, fun_name=None, **_):
         if (event == "/jax/core/compile/backend_compile_duration"
-                and fun_name == "jit(_optimizer_update)"):
+                and fun_name == f"jit({program})"):
             compiles.append(fun_name)
 
     jax.monitoring.register_event_duration_secs_listener(listen)
@@ -128,9 +205,27 @@ def test_update_compiles_once_per_stage(backend):
                                execution=ex, profile=profile)
             assert len(res.losses) == STEPS
             assert all(np.isfinite(res.losses))
-            assert len(compiles) == plan.n_stages
+            counts.append(len(compiles))
     finally:
         jax.monitoring.unregister_event_duration_listener(listen)
     for a, b in zip(jax.tree.leaves(ex.init_params), jax.tree.leaves(init)):
         assert not a.is_deleted()
         np.testing.assert_array_equal(np.asarray(a), b)
+    return plan.n_stages, counts
+
+
+@pytest.mark.parametrize("backend", ["emulated", "local"])
+def test_update_compiles_once_per_stage(backend):
+    """Two consecutive calls on one ``Execution``: each compiles every
+    stage's update program once over its steps, and the init params the
+    calls share stay readable."""
+    n_stages, counts = _compiles_per_call(backend, "_optimizer_update")
+    assert counts == [n_stages, n_stages]
+
+
+@pytest.mark.parametrize("backend", ["emulated", "local"])
+def test_pack_compiles_once_per_stage(backend):
+    """Two consecutive calls on one ``Execution``: each compiles every
+    stage's pack program once over its steps."""
+    n_stages, counts = _compiles_per_call(backend, "_pack")
+    assert counts == [n_stages, n_stages]

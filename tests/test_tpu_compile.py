@@ -114,5 +114,25 @@ def test_stage_update_keeps_small_leaves_unpadded(compile_for_tpu, one_chip):
     compiled = jax.jit(w._optimizer_update, donate_argnums=0).lower(
         jax.tree.map(on_chip, w.opt_state),
         on_chip(jax.ShapeDtypeStruct((n,), jnp.float32)),
-        on_chip(jax.ShapeDtypeStruct((), jnp.int32))).compile()
+        on_chip(jax.ShapeDtypeStruct((), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((), jnp.float32))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 4 * n
+
+
+def test_stage_pack_keeps_small_leaves_unpadded(compile_for_tpu, one_chip):
+    """The compiled pack of the same stage (its fp32 accumulator raveled and
+    concatenated on the device) holds at most the flat gradient's bytes of
+    temporaries beside its output, not the vector padded to 128 lanes."""
+    from repro.api import numeric_plan
+    from repro.serverless.runtime.worker import StageWorker, stage_instance_ranges
+
+    plan, _, ex = numeric_plan("xlstm-7-1-125m@reduced16", stages=1, dp=1,
+                               batch=4, seq=16)
+    span = stage_instance_ranges(ex.cfg, plan.x)[0]
+    w = StageWorker(ex.cfg, span, ex.init_params, mu=2, optimizer=ex.optimizer)
+    n = sum(w._sizes)
+    acc = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.float32, sharding=one_chip), w.params)
+    mem = jax.jit(w._pack).lower(acc).compile().memory_analysis()
+    assert 4 * n <= mem.output_size_in_bytes < 4 * (n + 1024)  # one tile
+    assert mem.temp_size_in_bytes <= 4 * n
